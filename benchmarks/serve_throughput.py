@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import TransformerLM
 from repro.serve import ContinuousBatcher, PagingSpec, Request, ServeEngine
 
@@ -898,6 +899,7 @@ def main():
                     help="write the perf report to PATH "
                     "(default BENCH_serve.json) for trajectory diffing")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get(args.arch, smoke=True)
     if args.attn_backend != "jnp":

@@ -70,11 +70,8 @@ class ArchConfig:
     # (causal + sliding window); MLA layers fall back to the jnp path and
     # recurrent mamba2/xLSTM blocks have no attention — see
     # repro.kernels.runtime.resolve_attn_backend for the fallback matrix.
-    # The attention kernels use TPU-specific Pallas primitives, so they
-    # COMPILE only on TPU and run in interpret mode everywhere else
-    # (including GPU) — functionally identical but slow; CPU CI relies on
-    # that to exercise the kernel code path, but off-TPU production serving
-    # should keep the "jnp" default.
+    # The kernels COMPILE on TPU and run in interpret mode elsewhere — CPU
+    # tests rely on that to exercise the kernel code path.
     attn_backend: str = "jnp"
     # unroll the period scan into a Python loop (exact HLO cost probes)
     unroll: bool = False

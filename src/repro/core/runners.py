@@ -20,7 +20,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.distributed import mix_all_gather, mix_ring, mixing_spec_for_band_graph
@@ -76,7 +75,7 @@ def bol_sharded(
 
     def run(w0, xs, ys, mu_mat):
         def body(w, _):
-            w = shard_map(
+            w = jax.shard_map(
                 local_step,
                 mesh=mesh,
                 in_specs=(
@@ -125,7 +124,7 @@ def bsr_sharded(
 
     def run(w0, xs, ys, minv):
         def body(w, _):
-            w = shard_map(
+            w = jax.shard_map(
                 local_step,
                 mesh=mesh,
                 in_specs=(

@@ -6,91 +6,31 @@ scores/outputs accumulate on-chip with an online softmax — arithmetic
 intensity is O(G) flops/byte, so the roofline is HBM bandwidth and the kernel
 objective is "touch every cache byte once".
 
-Grid (B, KVH, S/BLK_S); the sequence axis is innermost (sequential on TPU),
-carrying running (max, sum, acc) in VMEM scratch:
-
-  s        = q @ k_blk^T * scale          (G, BLK_S)   MXU
-  m_new    = max(m, rowmax(s))
-  p        = exp(s - m_new);  alpha = exp(m - m_new)
-  l        = alpha * l + rowsum(p)
-  acc      = alpha * acc + p @ v_blk      (G, hd)      MXU
-  (last block)  out = acc / l
-
-GQA group dim G rides along as the left matmul dim so every query group
-shares one streaming pass over its KV head. Causal/sliding-window masking is
-applied from the block's absolute positions vs the decoded position ``pos``.
+Decode is the one-token case of the chunked flash kernel
+(repro.kernels.prefill_attention.kernel.flash_attention): the query slab is
+the G query heads of each KV head, all at position ``pos``, so the chunk
+mask ``kv_idx <= pos + row // G`` is exactly the decode mask. Both entry
+points below keep their own jit (and trace cache); the kernel body is shared.
 
 ``paged_decode_attention_pallas`` is the block-table variant for the paged
 serving cache (repro.serve.paging): K/V live in a shared
 (num_blocks, block_size, KVH, hd) pool and each slot's pages are chased
-through a (B, max_blocks) block table. The table is a SCALAR-PREFETCH
-argument (pltpu.PrefetchScalarGridSpec), so the grid's innermost axis walks
-the slot's LOGICAL blocks while the BlockSpec index_map translates each step
-to its physical page — the gather never materializes a contiguous per-slot
-view in HBM; the online-softmax math is identical to the dense kernel.
-Unmapped table entries (0, the null block) only cover positions beyond
-``pos`` and are masked off like any future position.
+through a (B, max_blocks) block table prefetched as a scalar argument, so
+the gather never materializes a contiguous per-slot view in HBM. Unmapped
+table entries (0, the null block) only cover positions beyond ``pos`` and
+are masked off like any future position.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.runtime import resolve_interpret
-
-DEFAULT_BLOCK_S = 256
-NEG_INF = -1e30
-
-
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, block_s, scale, window):
-    sb = pl.program_id(2)
-    num_sb = pl.num_programs(2)
-
-    @pl.when(sb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0]  # (G, hd)
-    k = k_ref[0, :, 0, :]  # (BLK_S, hd)
-    v = v_ref[0, :, 0, :]  # (BLK_S, hd)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (G, BLK_S)
-
-    pos = pos_ref[0, 0]
-    kv_idx = sb * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kv_idx <= pos
-    if window is not None:
-        mask &= kv_idx > pos - window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_old = m_ref[:, 0]  # (G,)
-    l_old = l_ref[:, 0]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_old - m_new)  # (G,)
-    p = jnp.exp(s - m_new[:, None])  # (G, BLK_S)
-    l_new = alpha * l_old + jnp.sum(p, axis=1)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (G, hd)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-
-    @pl.when(sb == num_sb - 1)
-    def _fin():
-        l = l_ref[:, 0]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]).astype(
-            o_ref.dtype
-        )
+from repro.kernels.prefill_attention.kernel import (
+    DEFAULT_BLOCK_S,
+    dense_as_pool,
+    flash_attention,
+)
 
 
 @functools.partial(
@@ -106,101 +46,12 @@ def decode_attention_pallas(
     window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    # TPU-only primitives (pltpu VMEM scratch): interpret off-TPU by default
-    interpret = resolve_interpret(interpret, tpu_only=True)
-    b, kvh, g, hd = q.shape
-    s = k.shape[1]
-    g_pad = (-g) % 8
-    s_pad = (-s) % block_s
-    if g_pad:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, g_pad), (0, 0)))
-    if s_pad:
-        # padded positions are masked off via kv_idx > pos
-        k = jnp.pad(k, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
-    gp, sp = g + g_pad, s + s_pad
-    scale = float(1.0 / (hd ** 0.5))
-    # per-slot positions: one (1, 1) SMEM-sized block per batch row, so each
-    # grid row masks against ITS slot's decode depth (continuous batching)
-    pos_arr = jnp.broadcast_to(
-        jnp.asarray(pos, jnp.int32), (b,)
-    ).reshape(b, 1)
-
-    kernel = functools.partial(
-        _decode_kernel, block_s=block_s, scale=scale, window=window
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, kvh, sp // block_s),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, hh, ss: (bb, 0)),
-            pl.BlockSpec((1, gp, hd), lambda bb, hh, ss: (bb * kvh + hh, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, hd), lambda bb, hh, ss: (bb, ss, hh, 0)),
-            pl.BlockSpec((1, block_s, 1, hd), lambda bb, hh, ss: (bb, ss, hh, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, gp, hd), lambda bb, hh, ss: (bb * kvh + hh, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b * kvh, gp, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((gp, hd), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-        ],
+    k_pool, v_pool, table = dense_as_pool(k, v, block_s)
+    out = flash_attention(
+        q[:, :, None], k_pool, v_pool, table, pos, window=window,
         interpret=interpret,
-    )(pos_arr, q.reshape(b * kvh, gp, hd), k, v)
-    return out.reshape(b, kvh, gp, hd)[:, :, :g, :]
-
-
-# ------------------------------------------------------- paged (block-table)
-def _paged_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, page, scale, window):
-    """One step = one PAGE of one slot's block table. The physical page was
-    selected by the BlockSpec index_map from the prefetched table; here the
-    page only needs its LOGICAL span (ii * page + offset) for masking."""
-    ii = pl.program_id(2)
-    num_ii = pl.num_programs(2)
-
-    @pl.when(ii == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0]  # (G, hd)
-    k = k_ref[0, :, 0, :]  # (page, hd)
-    v = v_ref[0, :, 0, :]  # (page, hd)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (G, page)
-
-    pos = pos_ref[pl.program_id(0)]
-    kv_idx = ii * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kv_idx <= pos  # masks unmapped (null-block) pages entirely
-    if window is not None:
-        mask &= kv_idx > pos - window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_old = m_ref[:, 0]  # (G,)
-    l_old = l_ref[:, 0]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_old - m_new)  # (G,)
-    p = jnp.exp(s - m_new[:, None])  # (G, page)
-    l_new = alpha * l_old + jnp.sum(p, axis=1)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (G, hd)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-
-    @pl.when(ii == num_ii - 1)
-    def _fin():
-        l = l_ref[:, 0]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]).astype(
-            o_ref.dtype
-        )
+    )
+    return out[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -214,54 +65,12 @@ def paged_decode_attention_pallas(
     window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Flash-decode over the paged KV pool. Grid (B, KVH, max_blocks): the
-    sequence axis walks each slot's block table (innermost, sequential on
-    TPU) and the scalar-prefetched table turns logical step ``ii`` into the
-    physical page DMA'd for that step — O(1) extra HBM traffic vs dense."""
-    interpret = resolve_interpret(interpret, tpu_only=True)
-    b, kvh, g, hd = q.shape
-    page = k_pool.shape[1]
-    max_blocks = block_tables.shape[1]
-    g_pad = (-g) % 8
-    if g_pad:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, g_pad), (0, 0)))
-    gp = g + g_pad
-    scale = float(1.0 / (hd ** 0.5))
-    bt = jnp.asarray(block_tables, jnp.int32)
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-
-    kernel = functools.partial(
-        _paged_decode_kernel, page=page, scale=scale, window=window
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block table + positions drive the index_maps
-        grid=(b, kvh, max_blocks),
-        in_specs=[
-            pl.BlockSpec(
-                (1, gp, hd), lambda bb, hh, ii, bt, ps: (bb * kvh + hh, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, page, 1, hd),
-                lambda bb, hh, ii, bt, ps: (bt[bb, ii], 0, hh, 0),
-            ),
-            pl.BlockSpec(
-                (1, page, 1, hd),
-                lambda bb, hh, ii, bt, ps: (bt[bb, ii], 0, hh, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, gp, hd), lambda bb, hh, ii, bt, ps: (bb * kvh + hh, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((gp, hd), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-            pltpu.VMEM((gp, 128), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * kvh, gp, hd), q.dtype),
+    """Flash-decode over the paged KV pool: the innermost grid axis walks
+    each slot's block table and the scalar-prefetched table turns logical
+    step ``ii`` into the physical page DMA'd for that step — O(1) extra HBM
+    traffic vs dense."""
+    out = flash_attention(
+        q[:, :, None], k_pool, v_pool, block_tables, pos, window=window,
         interpret=interpret,
-    )(bt, pos_arr, q.reshape(b * kvh, gp, hd), k_pool, v_pool)
-    return out.reshape(b, kvh, gp, hd)[:, :, :g, :]
+    )
+    return out[:, :, 0]

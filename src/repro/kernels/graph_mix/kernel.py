@@ -30,11 +30,14 @@ DEFAULT_BLOCK_D = 512
 
 def _graph_mix_kernel(mu_ref, theta_ref, out_ref):
     mu = mu_ref[...]  # (m, m): mu[k, i]
-    theta = theta_ref[...]  # (m, BLK_D)
-    # out[i, :] = sum_k mu[k, i] theta[k, :]  ==  mu^T @ theta
+    theta = theta_ref[...].astype(jnp.float32)  # (m, BLK_D)
+    # out[i, :] = sum_k mu[k, i] theta[k, :]  ==  mu^T @ theta. HIGHEST: at
+    # the default precision the MXU takes f32 operands as one bf16 pass
+    # (2e-3 relative error on a v5e), and the mixing is iterated.
     out_ref[...] = jax.lax.dot_general(
         mu, theta, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     ).astype(out_ref.dtype)
 
 
@@ -49,7 +52,7 @@ def graph_mix_pallas(
     """mu: (m, m) float32; theta: (m, d). Returns mu^T @ theta, theta.dtype.
 
     d is padded to a multiple of block_d; m padded to a multiple of 8.
-    interpret=None auto-detects: compiled on TPU/GPU, interpreter elsewhere.
+    interpret=None auto-detects: compiled on TPU, interpreter elsewhere.
     """
     interpret = resolve_interpret(interpret)
     m, d = theta.shape

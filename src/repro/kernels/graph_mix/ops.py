@@ -1,5 +1,5 @@
 """Public ops: graph_mix — jit'd wrapper over the Pallas kernel (compiled on
-TPU/GPU, interpret mode — the real kernel body executed in Python —
+TPU, interpret mode — the real kernel body executed in Python —
 elsewhere; see repro.kernels.runtime) — plus graph_mix_tree, the batched
 variant over a pytree of stacked per-task leaves (serving adapter stores)."""
 import jax

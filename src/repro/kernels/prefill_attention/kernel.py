@@ -1,39 +1,44 @@
-"""Chunked flash-prefill Pallas TPU kernel: a (B, C) query slab vs the cache.
+"""Chunked flash-attention Pallas TPU kernel: a (B, C) query slab vs the cache.
 
-The serving prefill hot spot: admission writes a whole (B, C) prompt chunk
+The serving attention hot spot: admission writes a whole (B, C) prompt chunk
 into the KV cache at per-slot offsets (``model.prefill_step``), then every
 chunk token attends against the cache PREFIX it is allowed to see — query i
 of slot b sits at absolute position ``pos[b] + i`` and reads
-``kv_idx <= pos[b] + i`` only (sliding window subtracts the tail). That is
-exactly the decode mask with a per-row query offset, so this kernel is the
-decode kernel (repro.kernels.decode_attention) with the GQA group dim G
-widened to the C*G query-slab dim:
+``kv_idx <= pos[b] + i`` only (sliding window subtracts the tail). Decode is
+the C == 1 case (repro.kernels.decode_attention calls this kernel), so ONE
+kernel serves decode and prefill, dense and paged.
 
-  grid (B, KVH, S/BLK_S), sequence axis innermost (sequential on TPU),
-  running (max, sum, acc) carried in VMEM scratch:
+Every layout is walked as a paged pool: K/V live in a
+(num_blocks, block, KVH, hd) pool and each slot's blocks are chased through
+a (B, max_blocks) block table. The dense cache (B, S, KVH, hd) is the pool
+whose table maps slot b's i-th block to physical block b * (S / block) + i
+(a free reshape). The table and the per-slot positions are SCALAR-PREFETCH
+arguments (pltpu.PrefetchScalarGridSpec): the grid's innermost axis walks the
+slot's LOGICAL blocks while the BlockSpec index_map translates each step to
+its physical block, so the gather never materializes a contiguous per-slot
+view in HBM. Unmapped table entries (0, the null block) only cover positions
+beyond ``pos + C - 1`` for live slots and are masked off like any future
+position; slabs with no valid queries (slots mid-decode riding along a
+prefill dispatch) produce garbage rows the caller discards.
 
-    s     = q_slab @ k_blk^T * scale        (C*G, BLK_S)  MXU
+Grid (B, C*G / TQ, max_blocks), block axis innermost (sequential on TPU),
+running (max, sum, acc) carried in VMEM scratch per KV head:
+
+    s     = q_tile @ k_blk^T * scale        (TQ, BLK)   MXU
     mask  = kv_idx <= pos + row // G  [ & window ]
     m_new = max(m, rowmax(s));  p = exp(s - m_new)
     l     = exp(m - m_new) * l + rowsum(p)
-    acc   = exp(m - m_new) * acc + p @ v_blk  (C*G, hd)   MXU
+    acc   = exp(m - m_new) * acc + p @ v_blk  (TQ, hd)   MXU
     (last block)  out = acc / l
 
-The whole KV prefix streams HBM -> VMEM exactly once per (batch, kv head)
-while C*G queries amortize it — arithmetic intensity grows with the chunk
-width, which is what makes chunked prefill compute-bound where decode is
-bandwidth-bound.
-
-``paged_prefill_attention_pallas`` is the block-table variant for the paged
-serving cache (repro.serve.paging): K/V live in a shared
-(num_blocks, block_size, KVH, hd) pool and the grid's innermost axis walks
-each slot's LOGICAL blocks while the scalar-prefetched table
-(pltpu.PrefetchScalarGridSpec) translates every step to its physical page —
-no contiguous per-slot view is ever materialized in HBM. Unmapped table
-entries (0, the null block) only cover positions beyond ``pos + C - 1`` for
-live slots and are masked off like any future position; slabs with no valid
-queries (slots mid-decode riding along a prefill dispatch) produce garbage
-rows the caller discards, exactly as in the jnp path.
+Each grid step takes every KV head: a (1, BLK, KVH, hd) K/V tile, whose
+last two dims are the whole array's and so pass Mosaic's tiling rule — a
+(…, 1, hd) single-head tile of the same layout is refused. The kernel loops
+over heads in VMEM. Query rows are tiled by TQ (``QUERY_TILE``) so VMEM use
+is bounded at any chunk width; the KV prefix streams HBM -> VMEM once per
+(slot, query tile) while TQ queries amortize it — arithmetic intensity grows
+with the chunk width, which is what makes chunked prefill compute-bound where
+decode (one tile) is bandwidth-bound.
 """
 from __future__ import annotations
 
@@ -47,59 +52,148 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.runtime import resolve_interpret
 
 DEFAULT_BLOCK_S = 256
+# query rows per grid step: bounds the per-step VMEM (q/out tiles plus f32
+# acc/max/sum scratch for every KV head) independently of the chunk width
+QUERY_TILE = 128
 NEG_INF = -1e30
 
 
-def _prefill_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, *, block_s, gp, scale, window):
-    sb = pl.program_id(2)
-    num_sb = pl.num_programs(2)
+def _flash_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+                  acc_ref, m_ref, l_ref, *, block, group, scale, window):
+    """One step = one block of one slot's table for one query-row tile, all
+    KV heads. The physical block was selected by the BlockSpec index_map
+    from the prefetched table; here the block only needs its LOGICAL span
+    (ii * block + offset) for masking."""
+    del bt_ref  # consumed by the index_maps only
+    ii = pl.program_id(2)
+    num_ii = pl.num_programs(2)
 
-    @pl.when(sb == 0)
+    @pl.when(ii == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0]  # (C*gp, hd) — row i*gp + g is (chunk token i, group g)
-    k = k_ref[0, :, 0, :]  # (BLK_S, hd)
-    v = v_ref[0, :, 0, :]  # (BLK_S, hd)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (C*gp, BLK_S)
-
-    pos = pos_ref[0, 0]
-    # per-ROW query position: row r belongs to chunk token r // gp, which
-    # sits at absolute position pos + r // gp — the same kv_idx <= pos + i
-    # mask decode/prefill use in the jnp path (it also hides unwritten
-    # cache rows, so in-chunk causality falls out for free)
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // gp
-    kv_idx = sb * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kv_idx <= pos + q_idx
+    kvh, tq = q_ref.shape[1], q_ref.shape[2]
+    # row r is (chunk token r // G, group r % G) and sits at absolute
+    # position pos + r // G — the same kv_idx <= pos + i mask the jnp path
+    # uses (it also hides unwritten cache rows, so in-chunk causality falls
+    # out for free). Padding rows past C*G are garbage the wrapper drops.
+    pos = pos_ref[pl.program_id(0)]
+    row = pl.program_id(1) * tq + jax.lax.broadcasted_iota(
+        jnp.int32, (tq, block), 0
+    )
+    q_idx = row // group
+    kv_idx = ii * block + jax.lax.broadcasted_iota(jnp.int32, (tq, block), 1)
+    mask = kv_idx <= pos + q_idx  # masks unmapped (null-block) pages too
     if window is not None:
         mask &= kv_idx > pos + q_idx - window
-    s = jnp.where(mask, s, NEG_INF)
 
-    m_old = m_ref[:, 0]  # (C*gp,)
-    l_old = l_ref[:, 0]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new[:, None])  # (C*gp, BLK_S)
-    l_new = alpha * l_old + jnp.sum(p, axis=1)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (C*gp, hd)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    for h in range(kvh):
+        q = q_ref[0, h]  # (TQ, hd)
+        k = k_ref[0, :, h, :]  # (BLK, hd)
+        v = v_ref[0, :, h, :]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (TQ, BLK)
+        s = jnp.where(mask, s, NEG_INF)
 
-    @pl.when(sb == num_sb - 1)
+        m_old = m_ref[h][:, 0]  # (TQ,)
+        l_old = l_ref[h][:, 0]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new[:, None])  # (TQ, BLK)
+        l_new = alpha * l_old + jnp.sum(p, axis=1)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (TQ, hd)
+        acc_ref[h] = acc_ref[h] * alpha[:, None] + pv
+        m_ref[h] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new[:, None], l_ref.shape[1:])
+
+    @pl.when(ii == num_ii - 1)
     def _fin():
-        l = l_ref[:, 0]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]).astype(
-            o_ref.dtype
-        )
+        for h in range(kvh):
+            l = l_ref[h][:, 0]
+            o_ref[0, h] = (
+                acc_ref[h] / jnp.maximum(l, 1e-30)[:, None]
+            ).astype(o_ref.dtype)
+
+
+def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
+                    interpret):
+    """Shared body of every attention kernel entry point (not jitted: the
+    jitted wrappers each keep their own trace cache).
+
+    q: (B, KVH, C, G, hd) query slab grouped per KV head; k_pool/v_pool:
+    (num_blocks, block, KVH, hd); block_tables: (B, max_blocks); pos: () or
+    (B,) position of each slot's FIRST chunk token."""
+    interpret = resolve_interpret(interpret)
+    b, kvh, cq, g, hd = q.shape
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    block = k_pool.shape[1]
+    max_blocks = block_tables.shape[1]
+    rows = cq * g
+    # pad the flat C*G slab (not G) to whole sublanes, then to whole query
+    # tiles; row // G still recovers the chunk token for every real row
+    rp = rows + (-rows) % 8
+    tq = min(rp, QUERY_TILE)
+    rp += (-rp) % tq
+    qf = q.reshape(b, kvh, rows, hd)
+    if rp != rows:
+        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
+    scale = float(1.0 / (hd ** 0.5))
+
+    kernel = functools.partial(
+        _flash_kernel, block=block, group=g, scale=scale, window=window
+    )
+    q_spec = pl.BlockSpec(
+        (1, kvh, tq, hd), lambda bb, qi, ii, bt, ps: (bb, 0, qi, 0)
+    )
+    # all KV heads per step: a (1, block, 1, hd) single-head tile of the
+    # (…, KVH, hd) layout breaks Mosaic's (8, 128) tiling rule
+    kv_spec = pl.BlockSpec(
+        (1, block, kvh, hd), lambda bb, qi, ii, bt, ps: (bt[bb, ii], 0, 0, 0)
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # block table + positions drive the index_maps
+        grid=(b, rp // tq, max_blocks),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((kvh, tq, hd), jnp.float32),
+            pltpu.VMEM((kvh, tq, 128), jnp.float32),
+            pltpu.VMEM((kvh, tq, 128), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rp, hd), q.dtype),
+        interpret=interpret,
+    )(block_tables, pos, qf, k_pool, v_pool)
+    return out[:, :, :rows].reshape(b, kvh, cq, g, hd)
+
+
+def dense_as_pool(k, v, block_s):
+    """View a dense (B, S, KVH, hd) cache pair as a block pool plus the
+    identity block table: slot b's block i is physical block
+    b * (S / block_s) + i. Positions past S are padding the mask hides."""
+    b, s = k.shape[:2]
+    s_pad = (-s) % block_s
+    if s_pad:
+        k = jnp.pad(k, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
+    nblk = (s + s_pad) // block_s
+    tail = k.shape[2:]
+    table = jnp.arange(b * nblk, dtype=jnp.int32).reshape(b, nblk)
+    return (
+        k.reshape((b * nblk, block_s) + tail),
+        v.reshape((b * nblk, block_s) + tail),
+        table,
+    )
 
 
 @functools.partial(
@@ -115,103 +209,10 @@ def prefill_attention_pallas(
     window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    # TPU-only primitives (pltpu VMEM scratch): interpret off-TPU by default
-    interpret = resolve_interpret(interpret, tpu_only=True)
-    b, kvh, cq, g, hd = q.shape
-    s = k.shape[1]
-    g_pad = (-g) % 8
-    s_pad = (-s) % block_s
-    if g_pad:
-        # pad the GROUP dim (not the flat C*G product) so row // gp still
-        # recovers the chunk-token index exactly for every row
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, g_pad), (0, 0)))
-    if s_pad:
-        # padded positions are masked off via kv_idx > pos + i
-        k = jnp.pad(k, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, s_pad), (0, 0), (0, 0)))
-    gp, sp = g + g_pad, s + s_pad
-    rows = cq * gp
-    scale = float(1.0 / (hd ** 0.5))
-    pos_arr = jnp.broadcast_to(
-        jnp.asarray(pos, jnp.int32), (b,)
-    ).reshape(b, 1)
-
-    kernel = functools.partial(
-        _prefill_kernel, block_s=block_s, gp=gp, scale=scale, window=window
+    k_pool, v_pool, table = dense_as_pool(k, v, block_s)
+    return flash_attention(
+        q, k_pool, v_pool, table, pos, window=window, interpret=interpret
     )
-    out = pl.pallas_call(
-        kernel,
-        grid=(b, kvh, sp // block_s),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, hh, ss: (bb, 0)),
-            pl.BlockSpec((1, rows, hd), lambda bb, hh, ss: (bb * kvh + hh, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, hd), lambda bb, hh, ss: (bb, ss, hh, 0)),
-            pl.BlockSpec((1, block_s, 1, hd), lambda bb, hh, ss: (bb, ss, hh, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, rows, hd), lambda bb, hh, ss: (bb * kvh + hh, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b * kvh, rows, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((rows, hd), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(pos_arr, q.reshape(b * kvh, rows, hd), k, v)
-    return out.reshape(b, kvh, cq, gp, hd)[:, :, :, :g, :]
-
-
-# ------------------------------------------------------- paged (block-table)
-def _paged_prefill_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                          acc_ref, m_ref, l_ref, *, page, gp, scale, window):
-    """One step = one PAGE of one slot's block table. The physical page was
-    selected by the BlockSpec index_map from the prefetched table; here the
-    page only needs its LOGICAL span (ii * page + offset) for masking."""
-    ii = pl.program_id(2)
-    num_ii = pl.num_programs(2)
-
-    @pl.when(ii == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q = q_ref[0]  # (C*gp, hd)
-    k = k_ref[0, :, 0, :]  # (page, hd)
-    v = v_ref[0, :, 0, :]  # (page, hd)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (C*gp, page)
-
-    pos = pos_ref[pl.program_id(0)]
-    q_idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // gp
-    kv_idx = ii * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kv_idx <= pos + q_idx  # masks unmapped (null-block) pages too
-    if window is not None:
-        mask &= kv_idx > pos + q_idx - window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_old = m_ref[:, 0]
-    l_old = l_ref[:, 0]
-    m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_new = alpha * l_old + jnp.sum(p, axis=1)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-
-    @pl.when(ii == num_ii - 1)
-    def _fin():
-        l = l_ref[:, 0]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)[:, None]).astype(
-            o_ref.dtype
-        )
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -225,56 +226,7 @@ def paged_prefill_attention_pallas(
     window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Chunked flash-prefill over the paged KV pool. Grid (B, KVH,
-    max_blocks): the innermost axis walks each slot's block table
-    (sequential on TPU) and the scalar-prefetched table turns logical step
-    ``ii`` into the physical page DMA'd for that step — O(1) extra HBM
-    traffic vs dense, same online-softmax math."""
-    interpret = resolve_interpret(interpret, tpu_only=True)
-    b, kvh, cq, g, hd = q.shape
-    page = k_pool.shape[1]
-    max_blocks = block_tables.shape[1]
-    g_pad = (-g) % 8
-    if g_pad:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, g_pad), (0, 0)))
-    gp = g + g_pad
-    rows = cq * gp
-    scale = float(1.0 / (hd ** 0.5))
-    bt = jnp.asarray(block_tables, jnp.int32)
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-
-    kernel = functools.partial(
-        _paged_prefill_kernel, page=page, gp=gp, scale=scale, window=window
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block table + positions drive the index_maps
-        grid=(b, kvh, max_blocks),
-        in_specs=[
-            pl.BlockSpec(
-                (1, rows, hd), lambda bb, hh, ii, bt, ps: (bb * kvh + hh, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, page, 1, hd),
-                lambda bb, hh, ii, bt, ps: (bt[bb, ii], 0, hh, 0),
-            ),
-            pl.BlockSpec(
-                (1, page, 1, hd),
-                lambda bb, hh, ii, bt, ps: (bt[bb, ii], 0, hh, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, rows, hd), lambda bb, hh, ii, bt, ps: (bb * kvh + hh, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((rows, hd), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * kvh, rows, hd), q.dtype),
+    return flash_attention(
+        q, k_pool, v_pool, block_tables, pos, window=window,
         interpret=interpret,
-    )(bt, pos_arr, q.reshape(b * kvh, rows, hd), k_pool, v_pool)
-    return out.reshape(b, kvh, cq, gp, hd)[:, :, :, :g, :]
+    )

@@ -3,12 +3,13 @@
 Two concerns live here, both serving-platform policy rather than kernel
 math:
 
-* ``default_interpret`` / ``resolve_interpret`` — whether a Pallas kernel
-  runs COMPILED (TPU, and GPU for kernels without TPU-specific primitives)
+* ``resolve_interpret`` — whether a Pallas kernel runs COMPILED (on TPU)
   or in INTERPRET mode (everywhere else, so CPU test runs execute the real
   kernel bodies). Callers can always override with an explicit
   ``interpret=`` argument — CPU tests pass ``interpret=True`` so they stay
-  deterministic regardless of the machine they run on.
+  deterministic regardless of the machine they run on. Nothing here proves
+  a kernel was compiled: a chip run checks its program's compiled text for
+  ``tpu_custom_call`` (``chip_smoke.py``, ``tests/test_tpu_compile.py``).
 
 * ``resolve_attn_backend`` — the per-layer fallback matrix for the serving
   attention backend flag (``ArchConfig.attn_backend``). The Pallas flash
@@ -48,19 +49,12 @@ def pos_vector(pos, b: int) -> jax.Array:
     return jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
 
 
-def default_interpret(*, tpu_only: bool = False) -> bool:
-    """True when the Pallas kernel should run in interpreter mode.
-
-    tpu_only: kernels using TPU-specific primitives (pltpu scratch/grid
-    semantics) can only compile on TPU; generic kernels also compile on GPU
-    via the Triton lowering.
-    """
-    backends = ("tpu",) if tpu_only else ("tpu", "gpu")
-    return jax.default_backend() not in backends
-
-
-def resolve_interpret(interpret: bool | None, *, tpu_only: bool = False) -> bool:
-    return default_interpret(tpu_only=tpu_only) if interpret is None else interpret
+def resolve_interpret(interpret: bool | None) -> bool:
+    """An explicit ``interpret`` wins; otherwise compile on TPU and interpret
+    everywhere else."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def resolve_attn_backend(backend: str, *, mla: bool = False) -> str:

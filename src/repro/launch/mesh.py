@@ -11,6 +11,7 @@ outer axis in a real deployment; here just the outer mesh axis).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.sharding.rules import MeshAxes
 
@@ -18,7 +19,7 @@ from repro.sharding.rules import MeshAxes
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axes(*, multi_pod: bool = False) -> MeshAxes:
@@ -30,4 +31,6 @@ def mesh_axes(*, multi_pod: bool = False) -> MeshAxes:
 
 def make_smoke_mesh(data: int = 2, model: int = 2):
     """Tiny mesh for CPU multi-device tests (requires forced host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )

@@ -27,22 +27,21 @@ def run_sub(code: str, devices: int = 8):
 def test_mix_all_gather_matches_dense_oracle():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.core import band_graph
         from repro.core.distributed import mix_all_gather
 
         m, d = 8, 64
         g = band_graph(m, 2)
         mu = jnp.asarray(g.bol_mixing(0.5, 2.0, 0.05), jnp.float32)
-        mesh = jax.make_mesh((m,), ("task",))
+        mesh = jax.make_mesh((m,), ("task",), axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(0)
         theta = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
 
         def local_fn(th, mu_col):
             return mix_all_gather(th, mu_col[:, 0], "task")
 
-        fn = shard_map(local_fn, mesh=mesh,
+        fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(P("task", None), P(None, "task")),
                        out_specs=P("task", None))
         got = fn(theta, mu)
@@ -56,8 +55,7 @@ def test_mix_all_gather_matches_dense_oracle():
 def test_mix_ring_matches_band_mixing():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.core import band_graph
         from repro.core.distributed import mix_ring, mixing_spec_for_band_graph
 
@@ -67,11 +65,11 @@ def test_mix_ring_matches_band_mixing():
         spec = mixing_spec_for_band_graph(g, eta, tau, alpha)
         assert spec is not None
         self_w, nbr = spec
-        mesh = jax.make_mesh((m,), ("task",))
+        mesh = jax.make_mesh((m,), ("task",), axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(1)
         theta = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda th: mix_ring(th, self_w, nbr, "task", m),
             mesh=mesh, in_specs=P("task", None), out_specs=P("task", None))
         got = fn(theta)
@@ -88,7 +86,7 @@ def test_sharded_train_step_matches_single_device():
     loss as the unsharded step (sharding is an implementation detail)."""
     run_sub("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get
         from repro.core import GraphMultiTask, band_graph
         from repro.models import TransformerLM
@@ -110,7 +108,8 @@ def test_sharded_train_step_matches_single_device():
         }
         _, m_single = jax.jit(step)(state, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         ax = MeshAxes(("data",), "model", 2, 2)
         sspec = train_state_specs(cfg, state, ax)
         bspec = batch_specs(cfg, batch, ax)
@@ -123,12 +122,12 @@ def test_sharded_train_step_matches_single_device():
     """, devices=4)
 
 
-def test_dryrun_single_combo_compiles():
+def test_dryrun_single_combo_compiles(tmp_path):
     """End-to-end dry-run smoke (production 16x16 mesh on 512 host devices)."""
-    run_sub("""
+    run_sub(f"""
         import repro.launch.dryrun as dr
         r = dr.run_one("olmo_1b", "decode_32k", multi_pod=False, probes=False,
-                       out_dir="/tmp/dryrun_test")
+                       out_dir={str(tmp_path)!r})
         assert r["scanned"]["memory"]["temp_bytes"] > 0
         assert r["scanned"]["collectives"]["total_wire_bytes"] > 0
         print("OK")

@@ -25,6 +25,7 @@ def run_sub(code: str, devices: int = 8):
 def test_bol_sharded_matches_single_device():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.core import MultiTaskProblem, SQUARED, band_graph, bol
         from repro.core.runners import bol_sharded
         from repro.data.synthetic import generate_clustered_tasks
@@ -35,7 +36,7 @@ def test_bol_sharded_matches_single_device():
         x, y = map(jnp.asarray, tasks.sample(rng, n))
         graph = band_graph(m, 2)
         problem = MultiTaskProblem(graph, SQUARED, 0.5, 1.5)
-        mesh = jax.make_mesh((m,), ("task",))
+        mesh = jax.make_mesh((m,), ("task",), axis_types=(AxisType.Auto,))
         # ring collective path (band graph)
         w_ring = bol_sharded(problem, x, y, 60, mesh, use_ring=True)
         # all-gather path (generic graphs)
@@ -52,6 +53,7 @@ def test_bol_sharded_matches_single_device():
 def test_bsr_sharded_matches_single_device():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.core import MultiTaskProblem, SQUARED, band_graph, bsr
         from repro.core.runners import bsr_sharded
         from repro.data.synthetic import generate_clustered_tasks
@@ -62,7 +64,7 @@ def test_bsr_sharded_matches_single_device():
         x, y = map(jnp.asarray, tasks.sample(rng, n))
         graph = band_graph(m, 2)
         problem = MultiTaskProblem(graph, SQUARED, 0.5, 1.5)
-        mesh = jax.make_mesh((m,), ("task",))
+        mesh = jax.make_mesh((m,), ("task",), axis_types=(AxisType.Auto,))
         w_sh = bsr_sharded(problem, x, y, 80, mesh)
         ref = bsr(problem, x, y, num_iters=80, accelerated=False).w
         np.testing.assert_allclose(np.asarray(w_sh), np.asarray(ref),
