@@ -1,4 +1,4 @@
-"""Benchmark driver — one function per paper table/figure plus the roofline.
+"""Benchmark runner — one function per paper table/figure.
 
 Prints ``name,seconds,derived`` CSV summary lines (detailed per-benchmark
 CSVs land in reports/).

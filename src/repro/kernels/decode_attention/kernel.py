@@ -49,7 +49,7 @@ def decode_attention_pallas(
     k_pool, v_pool, table = dense_as_pool(k, v, block_s)
     out = flash_attention(
         q[:, :, None], k_pool, v_pool, table, pos, window=window,
-        interpret=interpret,
+        interpret=interpret, name="dense_attention_pallas",
     )
     return out[:, :, 0]
 
@@ -71,6 +71,6 @@ def paged_decode_attention_pallas(
     traffic vs dense."""
     out = flash_attention(
         q[:, :, None], k_pool, v_pool, block_tables, pos, window=window,
-        interpret=interpret,
+        interpret=interpret, name="paged_attention_pallas",
     )
     return out[:, :, 0]

@@ -73,5 +73,7 @@ def graph_mix_pallas(
         out_specs=pl.BlockSpec((mp, block_d), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((mp, dp), theta.dtype),
         interpret=interpret,
+        # the device operation's name in a profile, whatever calls it
+        name="graph_mix_pallas",
     )(mu_p, theta_p)
     return out[:m, :d]

@@ -122,9 +122,10 @@ def _flash_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
-                    interpret):
+                    interpret, name):
     """Shared body of every attention kernel entry point (not jitted: the
-    jitted wrappers each keep their own trace cache).
+    jitted wrappers each keep their own trace cache). ``name`` names the
+    device operation in a profile.
 
     q: (B, KVH, C, G, hd) query slab grouped per KV head; k_pool/v_pool:
     (num_blocks, block, KVH, hd); block_tables: (B, max_blocks); pos: () or
@@ -173,6 +174,7 @@ def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rp, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(block_tables, pos, qf, k_pool, v_pool)
     return out[:, :, :rows].reshape(b, kvh, cq, g, hd)
 
@@ -211,7 +213,8 @@ def prefill_attention_pallas(
 ) -> jax.Array:
     k_pool, v_pool, table = dense_as_pool(k, v, block_s)
     return flash_attention(
-        q, k_pool, v_pool, table, pos, window=window, interpret=interpret
+        q, k_pool, v_pool, table, pos, window=window, interpret=interpret,
+        name="dense_attention_pallas",
     )
 
 
@@ -228,5 +231,5 @@ def paged_prefill_attention_pallas(
 ) -> jax.Array:
     return flash_attention(
         q, k_pool, v_pool, block_tables, pos, window=window,
-        interpret=interpret,
+        interpret=interpret, name="paged_attention_pallas",
     )

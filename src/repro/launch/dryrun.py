@@ -16,8 +16,8 @@ Per combination this produces:
   * collective statistics parsed from the optimized HLO text,
   * optional "probe" lowerings with 1 and 2 UNROLLED pattern periods —
     XLA's cost analysis counts while-loop bodies ONCE, so the scanned
-    lowering undercounts depth; probes give exact per-period HLO numbers
-    that benchmarks/roofline.py extrapolates:
+    lowering undercounts depth; probes give exact per-period HLO numbers,
+    from which a full depth extrapolates as
         total ~= probe1 + (P - 1) * (probe2 - probe1).
 
 Usage:

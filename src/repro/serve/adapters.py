@@ -31,6 +31,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.delayed import per_source_stale
 from repro.core.graph import TaskGraph
@@ -240,27 +241,29 @@ class TaskAdapterStore:
 
         where ``stale`` picks each SOURCE task's iterate from the history
         ring at a bounded delay <= min(max_delay, len(hist) - 1) —
-        resampled per update, or pinned to the bound with fixed_delay."""
-        m = self.num_tasks
-        bound = min(self.max_delay, len(self._hist) - 1)
-        if self.fixed_delay:
-            delays = np.full(m, bound, np.int32)
-        else:
-            delays = self._rng.integers(0, bound + 1, size=m).astype(np.int32)
-        if bound == 0:
-            stale = self._hist[0]
-        else:
-            d = jnp.asarray(delays)
-            stacked = jax.tree.map(
-                lambda *ts: jnp.stack(ts), *self._hist
-            )  # (H, m, ...) leaves, newest first
-            stale = jax.tree.map(lambda h: per_source_stale(h, d), stacked)
-        new = graph_mix_tree(self.mu, stale)
-        new = jax.tree.map(
-            lambda t, g: t - self.lr * g.astype(t.dtype), new, self._grads
-        )
-        self._grads = jax.tree.map(jnp.zeros_like, self._grads)
-        self.raw = new
-        self._hist = [new] + self._hist[: self.max_delay]
-        self.updates += 1
-        self.refresh()
+        resampled per update, or pinned to the bound with fixed_delay.
+        Traced as the host span ``serve.adapters.update``."""
+        with TraceAnnotation("serve.adapters.update"):
+            m = self.num_tasks
+            bound = min(self.max_delay, len(self._hist) - 1)
+            if self.fixed_delay:
+                delays = np.full(m, bound, np.int32)
+            else:
+                delays = self._rng.integers(0, bound + 1, size=m).astype(np.int32)
+            if bound == 0:
+                stale = self._hist[0]
+            else:
+                d = jnp.asarray(delays)
+                stacked = jax.tree.map(
+                    lambda *ts: jnp.stack(ts), *self._hist
+                )  # (H, m, ...) leaves, newest first
+                stale = jax.tree.map(lambda h: per_source_stale(h, d), stacked)
+            new = graph_mix_tree(self.mu, stale)
+            new = jax.tree.map(
+                lambda t, g: t - self.lr * g.astype(t.dtype), new, self._grads
+            )
+            self._grads = jax.tree.map(jnp.zeros_like, self._grads)
+            self.raw = new
+            self._hist = [new] + self._hist[: self.max_delay]
+            self.updates += 1
+            self.refresh()

@@ -57,6 +57,14 @@ positions the masked attention reads. See ``docs/serving.md``.
 ``benchmarks/serve_throughput.py`` can assert the O(1)-dispatch property
 in both regimes.
 
+Tracing: each phase of ``step()`` is a ``jax.profiler.TraceAnnotation``
+span on the profiler's clock, about a microsecond each when no profiler
+runs. ``serve.step`` holds ``serve.retire``, ``serve.admit`` (admission,
+binding, block allocation), then per dispatch ``serve.assemble`` (host
+slab and uploads), ``serve.dispatch`` (enqueue), ``serve.device_wait``,
+``serve.fetch`` (device-to-host copy) and ``serve.emit``, and last
+``serve.finish``, which holds the store's ``serve.adapters.update``.
+
 Fault tolerance (see ``docs/serving.md`` "Fault tolerance & graceful
 degradation"): ``faults=`` injects a seeded ``repro.serve.faults.FaultPlan``
 at named seams (allocator exhaustion, dispatch exceptions, NaN lanes,
@@ -77,6 +85,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.model import TransformerLM
 from repro.serve.faults import FaultError, FaultPlan
@@ -135,8 +144,12 @@ class Request:
     # re-admission restores through one donated scatter.
     preemptions: int = 0
     _swap: dict | None = None
-    # bookkeeping stamped by the scheduler/executor
+    # bookkeeping stamped by the scheduler/executor; the three times are
+    # on the scheduler's clock, and a preempted, re-admitted request keeps
+    # its first admit_time as it keeps submit_time
     submit_time: float | None = None
+    admit_time: float | None = None  # first bound to a slot
+    first_token_time: float | None = None  # first generated token emitted
     prompt_done: int = 0  # prompt tokens already written to the cache
     cached_tokens: int = 0  # prompt tokens served from the prefix cache
     _arrival: int = 0
@@ -666,6 +679,8 @@ class ContinuousBatcher:
         else:
             tok = np.argmax(row, axis=-1)
         tok = int(tok) if np.ndim(tok) == 0 else np.asarray(tok, np.int32)
+        if not req.out:
+            req.first_token_time = self.scheduler.now()
         req.out.append(tok)
         if self.on_token is not None:
             self.on_token(req, tok)
@@ -764,16 +779,17 @@ class ContinuousBatcher:
         lifetime; when the free list cannot cover the policy head,
         admission stops (backpressure) until finishing requests release
         blocks."""
-        self._just_restored = set()
-        admitted = self.scheduler.admit(self.slots.free_slots(), self._try_bind)
-        newly = [s for s, _ in admitted]
-        # fresh prompts need their per-slot state reset on the first
-        # prefill dispatch; restored (swapped-in) slots must NOT be reset —
-        # their state was just scattered back in
-        self._needs_reset |= set(newly) - self._just_restored
-        # slots whose gulp a dispatch fault interrupted resume here
-        resumed = sorted(self._pending_prefill)
-        self._pending_prefill = set()
+        with TraceAnnotation("serve.admit"):
+            self._just_restored = set()
+            admitted = self.scheduler.admit(self.slots.free_slots(), self._try_bind)
+            newly = [s for s, _ in admitted]
+            # fresh prompts need their per-slot state reset on the first
+            # prefill dispatch; restored (swapped-in) slots must NOT be reset —
+            # their state was just scattered back in
+            self._needs_reset |= set(newly) - self._just_restored
+            # slots whose gulp a dispatch fault interrupted resume here
+            resumed = sorted(self._pending_prefill)
+            self._pending_prefill = set()
         if self.scheduler.chunk_budget is None and (newly or resumed):
             self._prefill_full(sorted(set(newly) | set(resumed)))
         return newly
@@ -791,7 +807,8 @@ class ContinuousBatcher:
         to emit. An injected dispatch fault aborts the round BEFORE the
         jitted call: the unfinished slots land in ``_pending_prefill`` and
         the next admission round resumes them from their cursors."""
-        task_ids = jnp.asarray(self.slots.task_ids(self._null_task))
+        with TraceAnnotation("serve.assemble"):
+            task_ids = jnp.asarray(self.slots.task_ids(self._null_task))
         c = self.prefill_chunk
         vlm = self.model.cfg.input_mode == "vlm"
         while True:
@@ -807,71 +824,84 @@ class ContinuousBatcher:
             ):
                 self._pending_prefill = set(pending)
                 raise FaultError("injected prefill dispatch failure")
-            tokens = np.zeros((self.num_slots, c), np.int32)
-            valid = np.zeros((self.num_slots, c), bool)
-            reset = np.zeros(self.num_slots, bool)
-            for s in pending:
-                if s in self._needs_reset:
-                    reset[s] = True
-            extras = {}
-            if vlm:
-                emb = np.zeros((self.num_slots, c, self.model.cfg.d_model),
-                               np.float32)
-                msk = np.zeros((self.num_slots, c), bool)
-            for s in pending:
-                req = self.slots.reqs[s]
-                d = req.prompt_done
-                t = np.asarray(req.tokens, np.int32)[d : d + c]
-                tokens[s, : len(t)] = t
-                valid[s, : len(t)] = True
-                if vlm and req.extras is not None:
-                    emb[s, : len(t)] = np.asarray(
-                        req.extras["vision_embeds"], np.float32
-                    )[d : d + len(t)]
-                    msk[s, : len(t)] = np.asarray(
-                        req.extras["vision_mask"], bool
-                    )[d : d + len(t)]
-            if vlm:
-                extras = {
-                    "vision_embeds": jnp.asarray(emb),
-                    "vision_mask": jnp.asarray(msk),
-                }
-            last, self.caches, positions = self._prefill_fn(
-                self.params, jnp.asarray(tokens), task_ids, self.caches,
-                jnp.asarray(self.pos), jnp.asarray(valid),
-                jnp.asarray(reset), extras, self._block_tables(),
-                self._adapter_tree(),
-            )
+            with TraceAnnotation("serve.assemble"):
+                tokens = np.zeros((self.num_slots, c), np.int32)
+                valid = np.zeros((self.num_slots, c), bool)
+                reset = np.zeros(self.num_slots, bool)
+                for s in pending:
+                    if s in self._needs_reset:
+                        reset[s] = True
+                extras = {}
+                if vlm:
+                    emb = np.zeros((self.num_slots, c, self.model.cfg.d_model),
+                                   np.float32)
+                    msk = np.zeros((self.num_slots, c), bool)
+                for s in pending:
+                    req = self.slots.reqs[s]
+                    d = req.prompt_done
+                    t = np.asarray(req.tokens, np.int32)[d : d + c]
+                    tokens[s, : len(t)] = t
+                    valid[s, : len(t)] = True
+                    if vlm and req.extras is not None:
+                        emb[s, : len(t)] = np.asarray(
+                            req.extras["vision_embeds"], np.float32
+                        )[d : d + len(t)]
+                        msk[s, : len(t)] = np.asarray(
+                            req.extras["vision_mask"], bool
+                        )[d : d + len(t)]
+                if vlm:
+                    extras = {
+                        "vision_embeds": jnp.asarray(emb),
+                        "vision_mask": jnp.asarray(msk),
+                    }
+                args = (
+                    self.params, jnp.asarray(tokens), task_ids, self.caches,
+                    jnp.asarray(self.pos), jnp.asarray(valid),
+                    jnp.asarray(reset), extras, self._block_tables(),
+                    self._adapter_tree(),
+                )
+            with TraceAnnotation("serve.dispatch"):
+                last, self.caches, positions = self._prefill_fn(*args)
             self.prefill_dispatches += 1
             self.prefill_tokens += int(valid.sum())
             self._consec_dispatch_faults = 0
             self._needs_reset -= set(pending)
+            positions, last_np = self._fetch(positions, last)
             self.slots.set_positions(positions)
-            last_np = np.asarray(last)
-            completed = []
-            for s in pending:
-                req = self.slots.reqs[s]
-                if req is None:  # cancelled from a streaming callback
-                    continue
-                req.prompt_done += int(valid[s].sum())
-                if req.prefill_remaining == 0:
-                    completed.append((s, req))
-            # the logits after each prompt's LAST token are the first
-            # generated token — emit them the dispatch they appear, exactly
-            # like the engine's prefill. submit() rejects empty prompts and
-            # prefix matching is capped at len(prompt) - 1, so every
-            # completing slot computed at least one prompt token and has
-            # real last-token logits here.
-            if self.quarantine and completed:
-                self._quarantine_scan(
-                    {s: last_np[s] for s, _ in completed}, completed
-                )
-            for s, req in completed:
-                if self.slots.reqs[s] is not req:  # quarantined/cancelled
-                    continue
-                self._register_prefix(s, req)
-                if not req.out:
-                    self._emit(req, row=last_np[s])
+            with TraceAnnotation("serve.emit"):
+                completed = []
+                for s in pending:
+                    req = self.slots.reqs[s]
+                    if req is None:  # cancelled from a streaming callback
+                        continue
+                    req.prompt_done += int(valid[s].sum())
+                    if req.prefill_remaining == 0:
+                        completed.append((s, req))
+                # the logits after each prompt's LAST token are the first
+                # generated token — emit them the dispatch they appear, exactly
+                # like the engine's prefill. submit() rejects empty prompts and
+                # prefix matching is capped at len(prompt) - 1, so every
+                # completing slot computed at least one prompt token and has
+                # real last-token logits here.
+                if self.quarantine and completed:
+                    self._quarantine_scan(
+                        {s: last_np[s] for s, _ in completed}, completed
+                    )
+                for s, req in completed:
+                    if self.slots.reqs[s] is not req:  # quarantined/cancelled
+                        continue
+                    self._register_prefix(s, req)
+                    if not req.out:
+                        self._emit(req, row=last_np[s])
+
+    @staticmethod
+    def _fetch(*outs):
+        """End of every dispatch: wait for its outputs on the device, then
+        copy them to the host."""
+        with TraceAnnotation("serve.device_wait"):
+            jax.block_until_ready(outs)
+        with TraceAnnotation("serve.fetch"):
+            return [np.asarray(o) for o in outs]
 
     def _quarantine_scan(self, rows: dict, items: list) -> None:
         """Lane quarantine: ONE vectorized host-side finiteness check over
@@ -916,35 +946,38 @@ class ContinuousBatcher:
             "dispatch", where="decode"
         ):
             raise FaultError("injected decode dispatch failure")
-        cb = self.model.cfg.num_codebooks
-        shape = (self.num_slots,) if cb <= 1 else (self.num_slots, cb)
-        tokens = np.zeros(shape, np.int32)
-        for s, req in self.slots.live_items():
-            tokens[s] = (
-                req.out[-1] if req.out else np.asarray(req.tokens)[-1]
+        with TraceAnnotation("serve.assemble"):
+            cb = self.model.cfg.num_codebooks
+            shape = (self.num_slots,) if cb <= 1 else (self.num_slots, cb)
+            tokens = np.zeros(shape, np.int32)
+            for s, req in self.slots.live_items():
+                tokens[s] = (
+                    req.out[-1] if req.out else np.asarray(req.tokens)[-1]
+                )
+            args = (
+                self.params, jnp.asarray(tokens),
+                jnp.asarray(self.slots.task_ids(self._null_task)),
+                self.caches, jnp.asarray(self.pos), jnp.asarray(live),
+                self._block_tables(), self._adapter_tree(),
             )
-        next_tok, step_logits, self.caches = self._tick_fn(
-            self.params, jnp.asarray(tokens),
-            jnp.asarray(self.slots.task_ids(self._null_task)),
-            self.caches, jnp.asarray(self.pos), jnp.asarray(live),
-            self._block_tables(), self._adapter_tree(),
-        )
+        with TraceAnnotation("serve.dispatch"):
+            next_tok, step_logits, self.caches = self._tick_fn(*args)
         self.ticks += 1
         self.decode_dispatches += 1
         self._consec_dispatch_faults = 0
         self.slots.advance_live()
-        next_np = np.asarray(next_tok)
-        logits_np = (
-            np.asarray(step_logits)
-            if self.sample_fn is not None or self.quarantine
-            else None
-        )
-        if self.quarantine:
-            items = self.slots.live_items()
-            self._quarantine_scan({s: logits_np[s] for s, _ in items}, items)
-        for s, req in self.slots.live_items():
-            row = logits_np[s] if logits_np is not None else None
-            self._emit(req, row=row, greedy=next_np[s])
+        if self.sample_fn is not None or self.quarantine:
+            next_np, logits_np = self._fetch(next_tok, step_logits)
+        else:
+            (next_np,) = self._fetch(next_tok)
+            logits_np = None
+        with TraceAnnotation("serve.emit"):
+            if self.quarantine:
+                items = self.slots.live_items()
+                self._quarantine_scan({s: logits_np[s] for s, _ in items}, items)
+            for s, req in self.slots.live_items():
+                row = logits_np[s] if logits_np is not None else None
+                self._emit(req, row=row, greedy=next_np[s])
 
     # ------------------------------------- SLA mode: fused prefill + decode
     def _interleaved_tick(self):
@@ -968,76 +1001,81 @@ class ContinuousBatcher:
         ):
             raise FaultError("injected mixed dispatch failure")
         c = self.prefill_chunk
-        plan = self.scheduler.plan_prefill(prefilling, c)
         cfg = self.model.cfg
         cb = cfg.num_codebooks
-        tok_shape = (
-            (self.num_slots, c) if cb <= 1 else (self.num_slots, c, cb)
-        )
-        tokens = np.zeros(tok_shape, np.int32)
-        valid = np.zeros((self.num_slots, c), bool)
-        reset = np.zeros(self.num_slots, bool)
         vlm = cfg.input_mode == "vlm"
-        if vlm:
-            emb = np.zeros((self.num_slots, c, cfg.d_model), np.float32)
-            msk = np.zeros((self.num_slots, c), bool)
-        for s, n in plan:
-            req = self.slots.reqs[s]
-            d = req.prompt_done
-            tokens[s, :n] = np.asarray(req.tokens, np.int32)[d : d + n]
-            valid[s, :n] = True
-            reset[s] = d == 0
-            if vlm and req.extras is not None:
-                emb[s, :n] = np.asarray(
-                    req.extras["vision_embeds"], np.float32
-                )[d : d + n]
-                msk[s, :n] = np.asarray(req.extras["vision_mask"], bool)[d : d + n]
-        for s, req in decoding:
-            tokens[s, 0] = (
-                req.out[-1] if req.out else np.asarray(req.tokens)[-1]
+        with TraceAnnotation("serve.assemble"):
+            plan = self.scheduler.plan_prefill(prefilling, c)
+            tok_shape = (
+                (self.num_slots, c) if cb <= 1 else (self.num_slots, c, cb)
             )
-            valid[s, 0] = True
-        extras = {}
-        if vlm:
-            extras = {
-                "vision_embeds": jnp.asarray(emb),
-                "vision_mask": jnp.asarray(msk),
-            }
-        last, self.caches, positions = self._prefill_fn(
-            self.params, jnp.asarray(tokens),
-            jnp.asarray(self.slots.task_ids(self._null_task)), self.caches,
-            jnp.asarray(self.pos), jnp.asarray(valid), jnp.asarray(reset),
-            extras, self._block_tables(), self._adapter_tree(),
-        )
+            tokens = np.zeros(tok_shape, np.int32)
+            valid = np.zeros((self.num_slots, c), bool)
+            reset = np.zeros(self.num_slots, bool)
+            if vlm:
+                emb = np.zeros((self.num_slots, c, cfg.d_model), np.float32)
+                msk = np.zeros((self.num_slots, c), bool)
+            for s, n in plan:
+                req = self.slots.reqs[s]
+                d = req.prompt_done
+                tokens[s, :n] = np.asarray(req.tokens, np.int32)[d : d + n]
+                valid[s, :n] = True
+                reset[s] = d == 0
+                if vlm and req.extras is not None:
+                    emb[s, :n] = np.asarray(
+                        req.extras["vision_embeds"], np.float32
+                    )[d : d + n]
+                    msk[s, :n] = np.asarray(req.extras["vision_mask"], bool)[d : d + n]
+            for s, req in decoding:
+                tokens[s, 0] = (
+                    req.out[-1] if req.out else np.asarray(req.tokens)[-1]
+                )
+                valid[s, 0] = True
+            extras = {}
+            if vlm:
+                extras = {
+                    "vision_embeds": jnp.asarray(emb),
+                    "vision_mask": jnp.asarray(msk),
+                }
+            args = (
+                self.params, jnp.asarray(tokens),
+                jnp.asarray(self.slots.task_ids(self._null_task)),
+                self.caches, jnp.asarray(self.pos), jnp.asarray(valid),
+                jnp.asarray(reset), extras, self._block_tables(),
+                self._adapter_tree(),
+            )
+        with TraceAnnotation("serve.dispatch"):
+            last, self.caches, positions = self._prefill_fn(*args)
         self.ticks += 1
         self.mixed_dispatches += 1
         self.prefill_tokens += sum(n for _, n in plan)
         self._consec_dispatch_faults = 0
+        positions, last_np = self._fetch(positions, last)
         self.slots.set_positions(positions)
-        last_np = np.asarray(last)
-        completed = []
-        for s, n in plan:
-            req = self.slots.reqs[s]
-            if req is None:  # cancelled from a streaming callback mid-round
-                continue
-            req.prompt_done += n
-            if req.prefill_remaining == 0:
-                completed.append((s, req))
-        if self.quarantine:
-            items = completed + [
-                (s, r) for s, r in decoding if self.slots.reqs[s] is r
-            ]
-            self._quarantine_scan({s: last_np[s] for s, _ in items}, items)
-        for s, req in completed:
-            if self.slots.reqs[s] is not req:  # quarantined/cancelled
-                continue
-            self._register_prefix(s, req)
-            if not req.out:  # restored decode slots have already emitted
-                self._emit(req, row=last_np[s])  # first generated token
-        for s, req in decoding:
-            if self.slots.reqs[s] is not req:  # quarantined/cancelled
-                continue
-            self._emit(req, row=last_np[s])
+        with TraceAnnotation("serve.emit"):
+            completed = []
+            for s, n in plan:
+                req = self.slots.reqs[s]
+                if req is None:  # cancelled from a streaming callback mid-round
+                    continue
+                req.prompt_done += n
+                if req.prefill_remaining == 0:
+                    completed.append((s, req))
+            if self.quarantine:
+                items = completed + [
+                    (s, r) for s, r in decoding if self.slots.reqs[s] is r
+                ]
+                self._quarantine_scan({s: last_np[s] for s, _ in items}, items)
+            for s, req in completed:
+                if self.slots.reqs[s] is not req:  # quarantined/cancelled
+                    continue
+                self._register_prefix(s, req)
+                if not req.out:  # restored decode slots have already emitted
+                    self._emit(req, row=last_np[s])  # first generated token
+            for s, req in decoding:
+                if self.slots.reqs[s] is not req:  # quarantined/cancelled
+                    continue
+                self._emit(req, row=last_np[s])
 
     # ------------------------------------------------------------ driving
     def step(self):
@@ -1049,20 +1087,25 @@ class ContinuousBatcher:
         jitted call, so no state was mutated) aborts the round; the next
         round retries the same work. ``max_retries`` consecutive failures
         fail every in-flight request terminally instead of spinning."""
-        if self.faults is not None:
-            self.faults.set_tick(self.ticks)
-        self._retire_expired()
-        try:
-            self._admit()
-            if self.scheduler.chunk_budget is None:
-                self._finish_ready()  # prefill alone may satisfy max_new
-                if self.slots.any_live():
-                    self.tick()
-            else:
-                self._interleaved_tick()
-        except FaultError as e:
-            self._note_dispatch_fault(e)
-        self._finish_ready()
+        with TraceAnnotation("serve.step"):
+            if self.faults is not None:
+                self.faults.set_tick(self.ticks)
+            with TraceAnnotation("serve.retire"):
+                self._retire_expired()
+            try:
+                self._admit()
+                if self.scheduler.chunk_budget is None:
+                    # prefill alone may satisfy max_new
+                    with TraceAnnotation("serve.finish"):
+                        self._finish_ready()
+                    if self.slots.any_live():
+                        self.tick()
+                else:
+                    self._interleaved_tick()
+            except FaultError as e:
+                self._note_dispatch_fault(e)
+            with TraceAnnotation("serve.finish"):
+                self._finish_ready()
 
     def _note_dispatch_fault(self, e: FaultError) -> None:
         """Tick-level dispatch fault bookkeeping: count it, and once

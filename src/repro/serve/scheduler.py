@@ -141,7 +141,9 @@ class Scheduler:
         the slot, or returns False when the allocator cannot cover the
         request — which STOPS admission (head-of-line backpressure in
         policy order; see module docstring for why blocked heads are not
-        skipped). Returns the [(slot, request)] admitted."""
+        skipped). Returns the [(slot, request)] admitted, each stamped
+        ``admit_time`` at its first admission (a preempted request that
+        comes back keeps it)."""
         admitted = []
         free = list(free_slots)
         now = None
@@ -164,6 +166,8 @@ class Scheduler:
                 continue
             slot = free.pop(0)
             self.queue.remove(req)
+            if getattr(req, "admit_time", None) is None:
+                req.admit_time = self.now()
             admitted.append((slot, req))
         return admitted
 
