@@ -16,9 +16,9 @@ points below keep their own jit (and trace cache); the kernel body is shared.
 serving cache (repro.serve.paging): K/V live in a shared
 (num_blocks, block_size, KVH, hd) pool and each slot's pages are chased
 through a (B, max_blocks) block table prefetched as a scalar argument, so
-the gather never materializes a contiguous per-slot view in HBM. Unmapped
-table entries (0, the null block) only cover positions beyond ``pos`` and
-are masked off like any future position.
+the gather never materializes a contiguous per-slot view in HBM. Each
+slot's walk stops at its last live block (``kv_len``: ``pos + 1``, or 0 for
+a dead slot), as the shared kernel's docstring says.
 """
 from __future__ import annotations
 
@@ -41,6 +41,7 @@ def decode_attention_pallas(
     k: jax.Array,  # (B, S, KVH, hd)
     v: jax.Array,  # (B, S, KVH, hd)
     pos: jax.Array,  # () int32 shared, or (B,) per-slot decode positions
+    kv_len: jax.Array | None = None,  # (B,) keys visible (0: dead slot)
     *,
     block_s: int = DEFAULT_BLOCK_S,
     window: int | None = None,
@@ -49,7 +50,7 @@ def decode_attention_pallas(
     k_pool, v_pool, table = dense_as_pool(k, v, block_s)
     out = flash_attention(
         q[:, :, None], k_pool, v_pool, table, pos, window=window,
-        interpret=interpret, name="dense_attention_pallas",
+        interpret=interpret, name="dense_attention_pallas", kv_len=kv_len,
     )
     return out[:, :, 0]
 
@@ -61,6 +62,7 @@ def paged_decode_attention_pallas(
     v_pool: jax.Array,  # (num_blocks, block_size, KVH, hd)
     block_tables: jax.Array,  # (B, max_blocks) physical page ids (0 = null)
     pos: jax.Array,  # (B,) or () per-slot decode positions
+    kv_len: jax.Array | None = None,  # (B,) keys visible (0: dead slot)
     *,
     window: int | None = None,
     interpret: bool | None = None,
@@ -71,6 +73,6 @@ def paged_decode_attention_pallas(
     traffic vs dense."""
     out = flash_attention(
         q[:, :, None], k_pool, v_pool, block_tables, pos, window=window,
-        interpret=interpret, name="paged_attention_pallas",
+        interpret=interpret, name="paged_attention_pallas", kv_len=kv_len,
     )
     return out[:, :, 0]

@@ -28,12 +28,13 @@ def decode_attention(
     *,
     window: int | None = None,
     block_s: int = 256,
+    kv_len: jax.Array | None = None,  # (B,) keys visible (0: dead slot)
 ) -> jax.Array:
     b, one, h, hd = q.shape
     kvh = k_cache.shape[2]
     qg = q.reshape(b, kvh, h // kvh, hd)
     out = decode_attention_pallas(
-        qg, k_cache, v_cache, pos_vector(pos, b),
+        qg, k_cache, v_cache, pos_vector(pos, b), kv_len,
         block_s=block_s, window=window,
     )
     return out.reshape(b, 1, h, hd)
@@ -47,12 +48,13 @@ def paged_decode_attention(
     pos: jax.Array,  # () shared or (B,) per-slot decode positions
     *,
     window: int | None = None,
+    kv_len: jax.Array | None = None,  # (B,) keys visible (0: dead slot)
 ) -> jax.Array:
     b, one, h, hd = q.shape
     kvh = k_pool.shape[2]
     qg = q.reshape(b, kvh, h // kvh, hd)
     out = paged_decode_attention_pallas(
         qg, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
-        pos_vector(pos, b), window=window,
+        pos_vector(pos, b), kv_len, window=window,
     )
     return out.reshape(b, 1, h, hd)

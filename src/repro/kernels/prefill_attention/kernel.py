@@ -16,14 +16,21 @@ whose table maps slot b's i-th block to physical block b * (S / block) + i
 arguments (pltpu.PrefetchScalarGridSpec): the grid's innermost axis walks the
 slot's LOGICAL blocks while the BlockSpec index_map translates each step to
 its physical block, so the gather never materializes a contiguous per-slot
-view in HBM. Unmapped table entries (0, the null block) only cover positions
-beyond ``pos + C - 1`` for live slots and are masked off like any future
-position; slabs with no valid queries (slots mid-decode riding along a
-prefill dispatch) produce garbage rows the caller discards.
+view in HBM.
+
+The walk is bounded by ``kv_len[b]``, a third scalar-prefetch argument: the
+count of key positions slot b's valid queries may see (``pos + n_valid``; 0
+for a slot with no valid query). Steps past the slot's last live block run
+no body, and their index_map repeats that block, so the pipeline issues no
+copy: a step costs grid overhead only, however long the table is. Valid rows
+are bitwise those of a walk over every entry: each skipped block was masked
+for all of them (``alpha = 1``, ``p = 0``). A slot that walks no block
+writes 0; rows past a slot's valid queries are garbage the caller discards.
 
 Grid (B, C*G / TQ, max_blocks), block axis innermost (sequential on TPU),
 running (max, sum, acc) carried in VMEM scratch per KV head:
 
+    (ii * BLK < kv_len only)
     s     = q_tile @ k_blk^T * scale        (TQ, BLK)   MXU
     mask  = kv_idx <= pos + row // G  [ & window ]
     m_new = max(m, rowmax(s));  p = exp(s - m_new)
@@ -58,15 +65,19 @@ QUERY_TILE = 128
 NEG_INF = -1e30
 
 
-def _flash_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+def _flash_kernel(bt_ref, pos_ref, kv_len_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, block, group, scale, window):
     """One step = one block of one slot's table for one query-row tile, all
     KV heads. The physical block was selected by the BlockSpec index_map
     from the prefetched table; here the block only needs its LOGICAL span
-    (ii * block + offset) for masking."""
+    (ii * block + offset) for masking. Blocks at or past ``kv_len`` are
+    skipped."""
     del bt_ref  # consumed by the index_maps only
-    ii = pl.program_id(2)
+    # grid indices are read here: interpret mode resolves them only outside
+    # the pl.when bodies
+    bb, qi, ii = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     num_ii = pl.num_programs(2)
+    kvh, tq = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(ii == 0)
     def _init():
@@ -74,43 +85,48 @@ def _flash_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    kvh, tq = q_ref.shape[1], q_ref.shape[2]
-    # row r is (chunk token r // G, group r % G) and sits at absolute
-    # position pos + r // G — the same kv_idx <= pos + i mask the jnp path
-    # uses (it also hides unwritten cache rows, so in-chunk causality falls
-    # out for free). Padding rows past C*G are garbage the wrapper drops.
-    pos = pos_ref[pl.program_id(0)]
-    row = pl.program_id(1) * tq + jax.lax.broadcasted_iota(
-        jnp.int32, (tq, block), 0
-    )
-    q_idx = row // group
-    kv_idx = ii * block + jax.lax.broadcasted_iota(jnp.int32, (tq, block), 1)
-    mask = kv_idx <= pos + q_idx  # masks unmapped (null-block) pages too
-    if window is not None:
-        mask &= kv_idx > pos + q_idx - window
+    @pl.when(ii * block < kv_len_ref[bb])
+    def _step():
+        # row r is (chunk token r // G, group r % G) and sits at absolute
+        # position pos + r // G — the same kv_idx <= pos + i mask the jnp
+        # path uses (it also hides unwritten cache rows, so in-chunk
+        # causality falls out for free). Padding rows past C*G are garbage
+        # the wrapper drops.
+        pos = pos_ref[bb]
+        row = qi * tq + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, block), 0
+        )
+        q_idx = row // group
+        kv_idx = ii * block + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, block), 1
+        )
+        mask = kv_idx <= pos + q_idx  # masks unmapped (null-block) pages too
+        if window is not None:
+            mask &= kv_idx > pos + q_idx - window
 
-    for h in range(kvh):
-        q = q_ref[0, h]  # (TQ, hd)
-        k = k_ref[0, :, h, :]  # (BLK, hd)
-        v = v_ref[0, :, h, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (TQ, BLK)
-        s = jnp.where(mask, s, NEG_INF)
+        for h in range(kvh):
+            q = q_ref[0, h]  # (TQ, hd)
+            k = k_ref[0, :, h, :]  # (BLK, hd)
+            v = v_ref[0, :, h, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # (TQ, BLK)
+            s = jnp.where(mask, s, NEG_INF)
 
-        m_old = m_ref[h][:, 0]  # (TQ,)
-        l_old = l_ref[h][:, 0]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(s - m_new[:, None])  # (TQ, BLK)
-        l_new = alpha * l_old + jnp.sum(p, axis=1)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (TQ, hd)
-        acc_ref[h] = acc_ref[h] * alpha[:, None] + pv
-        m_ref[h] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
-        l_ref[h] = jnp.broadcast_to(l_new[:, None], l_ref.shape[1:])
+            m_old = m_ref[h][:, 0]  # (TQ,)
+            l_old = l_ref[h][:, 0]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
+            alpha = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new[:, None])  # (TQ, BLK)
+            l_new = alpha * l_old + jnp.sum(p, axis=1)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (TQ, hd)
+            acc_ref[h] = acc_ref[h] * alpha[:, None] + pv
+            m_ref[h] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new[:, None], l_ref.shape[1:])
 
     @pl.when(ii == num_ii - 1)
     def _fin():
@@ -122,18 +138,23 @@ def _flash_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
-                    interpret, name):
+                    interpret, name, kv_len=None):
     """Shared body of every attention kernel entry point (not jitted: the
     jitted wrappers each keep their own trace cache). ``name`` names the
     device operation in a profile.
 
     q: (B, KVH, C, G, hd) query slab grouped per KV head; k_pool/v_pool:
     (num_blocks, block, KVH, hd); block_tables: (B, max_blocks); pos: () or
-    (B,) position of each slot's FIRST chunk token."""
+    (B,) position of each slot's FIRST chunk token; kv_len: (B,) key
+    positions each slot's valid queries may see (0: no valid query), which
+    bounds the slot's walk; None means ``pos + C`` for every slot."""
     interpret = resolve_interpret(interpret)
     b, kvh, cq, g, hd = q.shape
     block_tables = jnp.asarray(block_tables, jnp.int32)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    if kv_len is None:
+        kv_len = pos + cq
+    kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (b,))
     block = k_pool.shape[1]
     max_blocks = block_tables.shape[1]
     rows = cq * g
@@ -151,15 +172,20 @@ def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
         _flash_kernel, block=block, group=g, scale=scale, window=window
     )
     q_spec = pl.BlockSpec(
-        (1, kvh, tq, hd), lambda bb, qi, ii, bt, ps: (bb, 0, qi, 0)
+        (1, kvh, tq, hd), lambda bb, qi, ii, bt, ps, kl: (bb, 0, qi, 0)
     )
+
+    def kv_block(bb, qi, ii, bt, ps, kl):
+        # steps past the slot's last live block repeat it: no new copy
+        last = jnp.maximum((kl[bb] + block - 1) // block - 1, 0)
+        return (bt[bb, jnp.minimum(ii, last)], 0, 0, 0)
+
     # all KV heads per step: a (1, block, 1, hd) single-head tile of the
     # (…, KVH, hd) layout breaks Mosaic's (8, 128) tiling rule
-    kv_spec = pl.BlockSpec(
-        (1, block, kvh, hd), lambda bb, qi, ii, bt, ps: (bt[bb, ii], 0, 0, 0)
-    )
+    kv_spec = pl.BlockSpec((1, block, kvh, hd), kv_block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block table + positions drive the index_maps
+        # block table, positions and walk lengths drive the index_maps
+        num_scalar_prefetch=3,
         grid=(b, rp // tq, max_blocks),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
@@ -175,7 +201,7 @@ def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rp, hd), q.dtype),
         interpret=interpret,
         name=name,
-    )(block_tables, pos, qf, k_pool, v_pool)
+    )(block_tables, pos, kv_len, qf, k_pool, v_pool)
     return out[:, :, :rows].reshape(b, kvh, cq, g, hd)
 
 
@@ -206,6 +232,7 @@ def prefill_attention_pallas(
     k: jax.Array,  # (B, S, KVH, hd)
     v: jax.Array,  # (B, S, KVH, hd)
     pos: jax.Array,  # (B,) per-slot positions of the chunk's FIRST token
+    kv_len: jax.Array | None = None,  # (B,) keys visible to valid queries
     *,
     block_s: int = DEFAULT_BLOCK_S,
     window: int | None = None,
@@ -214,7 +241,7 @@ def prefill_attention_pallas(
     k_pool, v_pool, table = dense_as_pool(k, v, block_s)
     return flash_attention(
         q, k_pool, v_pool, table, pos, window=window, interpret=interpret,
-        name="dense_attention_pallas",
+        name="dense_attention_pallas", kv_len=kv_len,
     )
 
 
@@ -225,11 +252,12 @@ def paged_prefill_attention_pallas(
     v_pool: jax.Array,  # (num_blocks, block_size, KVH, hd)
     block_tables: jax.Array,  # (B, max_blocks) physical page ids (0 = null)
     pos: jax.Array,  # (B,) per-slot positions of the chunk's FIRST token
+    kv_len: jax.Array | None = None,  # (B,) keys visible to valid queries
     *,
     window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     return flash_attention(
         q, k_pool, v_pool, block_tables, pos, window=window,
-        interpret=interpret, name="paged_attention_pallas",
+        interpret=interpret, name="paged_attention_pallas", kv_len=kv_len,
     )

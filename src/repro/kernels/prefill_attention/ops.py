@@ -24,6 +24,7 @@ def prefill_attention(
     *,
     window: int | None = None,
     block_s: int = 256,
+    kv_len: jax.Array | None = None,  # (B,) keys visible to valid queries
 ) -> jax.Array:
     from repro.kernels.prefill_attention.kernel import prefill_attention_pallas
 
@@ -33,7 +34,7 @@ def prefill_attention(
     # whole slab shares one streaming pass over its head's cache
     qg = q.reshape(b, cq, kvh, h // kvh, hd).transpose(0, 2, 1, 3, 4)
     out = prefill_attention_pallas(
-        qg, k_cache, v_cache, pos_vector(pos, b),
+        qg, k_cache, v_cache, pos_vector(pos, b), kv_len,
         block_s=block_s, window=window,
     )
     return out.transpose(0, 2, 1, 3, 4).reshape(b, cq, h, hd)
@@ -47,6 +48,7 @@ def paged_prefill_attention(
     pos: jax.Array,  # () shared or (B,) per-slot first-token positions
     *,
     window: int | None = None,
+    kv_len: jax.Array | None = None,  # (B,) keys visible to valid queries
 ) -> jax.Array:
     from repro.kernels.prefill_attention.kernel import (
         paged_prefill_attention_pallas,
@@ -57,6 +59,6 @@ def paged_prefill_attention(
     qg = q.reshape(b, cq, kvh, h // kvh, hd).transpose(0, 2, 1, 3, 4)
     out = paged_prefill_attention_pallas(
         qg, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
-        pos_vector(pos, b), window=window,
+        pos_vector(pos, b), kv_len, window=window,
     )
     return out.transpose(0, 2, 1, 3, 4).reshape(b, cq, h, hd)

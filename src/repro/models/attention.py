@@ -176,6 +176,7 @@ def cached_attend(
     sliding_window: int | None = None,
     backend: str = "jnp",
     block_tables: Array | None = None,
+    kv_len: Array | None = None,
 ) -> Array:
     """GQA chunk-of-queries attention against the cache, dispatching on the
     serving attention backend (``ArchConfig.attn_backend``, already resolved
@@ -195,6 +196,9 @@ def cached_attend(
         on TPU, interpret mode elsewhere (repro.kernels.runtime), identical
         ``kv_idx <= pos + i`` masking — token parity with "jnp" is pinned by
         tests/test_serve_backend.py and benchmarks/serve_throughput.py.
+        ``kv_len`` (B,), the keys each slot's valid queries may see (0 for
+        a slot with none), bounds each slot's walk over its blocks; the
+        jnp path ignores it.
     """
     if backend == "pallas":
         from repro.kernels.decode_attention.ops import (
@@ -209,10 +213,11 @@ def cached_attend(
         decode = q.shape[1] == 1  # static under jit: C is a trace constant
         if block_tables is None:
             op = decode_attention if decode else prefill_attention
-            return op(q, k_cache, v_cache, pos, window=sliding_window)
+            return op(q, k_cache, v_cache, pos, window=sliding_window,
+                      kv_len=kv_len)
         op = paged_decode_attention if decode else paged_prefill_attention
         return op(q, k_cache, v_cache, block_tables, pos,
-                  window=sliding_window)
+                  window=sliding_window, kv_len=kv_len)
     if block_tables is not None:
         k_cache = gather_pages(k_cache, block_tables)
         v_cache = gather_pages(v_cache, block_tables)

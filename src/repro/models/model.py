@@ -539,20 +539,22 @@ class TransformerLM:
         mask = hit.reshape(hit.shape + (1,) * (cache.ndim - 2))
         return jnp.where(mask, val.astype(cache.dtype), cache)
 
-    def _make_attend(self, pos, block_tables):
+    def _make_attend(self, pos, block_tables, kv_len):
         """Backend-dispatching GQA attention closure for one serving
         dispatch. The effective backend is resolved ONCE per trace through
         the fallback matrix (``repro.kernels.runtime.resolve_attn_backend``):
         "pallas" serves GQA from the flash kernels (dense or block-table
         paged — the paged kernels consume the pool + table directly, no
         gathered view); MLA configs resolve to "jnp" and never build this
-        closure's pallas path. All arguments are trace-time constants or
-        traced arrays, so varying batch CONTENT never retraces."""
+        closure's pallas path. kv_len: (B,) keys each slot's valid queries
+        may see (0 for none), which bounds the Pallas kernel's walk. All
+        arguments are trace-time constants or traced arrays, so varying
+        batch CONTENT never retraces."""
         c = self.cfg
         backend = resolve_attn_backend(c.attn_backend, mla=c.use_mla)
         return lambda q, kc, vc: attn_lib.cached_attend(
             q, kc, vc, pos, sliding_window=c.sliding_window,
-            backend=backend, block_tables=block_tables,
+            backend=backend, block_tables=block_tables, kv_len=kv_len,
         )
 
     def _gather_adapters(self, adapters, task_ids):
@@ -649,10 +651,11 @@ class TransformerLM:
         return x + ff, new_cache
 
     def _block_decode(
-        self, kind, p, x, cache, pos, router_bias, live=None,
+        self, kind, p, x, cache, pos, kv_len, router_bias, live=None,
         block_tables=None, ad=None,
     ):
-        """pos: (B,) per-slot positions; live: optional (B,) slot mask;
+        """pos: (B,) per-slot positions; kv_len: (B,) keys visible to each
+        slot's query (0 for a dead slot); live: optional (B,) slot mask;
         block_tables: optional (B, max_blocks) — paged attention caches
         (cache entries are shared pools, writes scatter through the table,
         reads attend over the gathered per-slot view); ad: optional per-row
@@ -667,7 +670,7 @@ class TransformerLM:
                     cc, new, pos, block_tables, live
                 )
                 view = lambda cc: attn_lib.gather_pages(cc, block_tables)
-            attend = self._make_attend(pos, block_tables)
+            attend = self._make_attend(pos, block_tables, kv_len)
             return self._attn_block(
                 kind, p, x, cache, pos, router_bias, live, write, view,
                 attend, ad,
@@ -771,6 +774,7 @@ class TransformerLM:
         x = self._constrain(self._embed(params, batch))
         b = x.shape[0]
         pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+        kv_len = pos + 1 if live is None else jnp.where(live, pos + 1, 0)
         stage_ad = task_ad = None
         if adapters is not None:
             stage_ad, task_ad = self._gather_adapters(
@@ -780,7 +784,7 @@ class TransformerLM:
         x, new_caches = self._run_cached_stages(
             params, x, caches,
             lambda kind, p, h, cache, ad: self._block_decode(
-                kind, p, h, cache, pos, rb, live, block_tables, ad
+                kind, p, h, cache, pos, kv_len, rb, live, block_tables, ad
             ),
             stage_ad,
         )
@@ -788,13 +792,15 @@ class TransformerLM:
         return logits, new_caches
 
     def _block_prefill(
-        self, kind, p, x, cache, pos, valid, router_bias, block_tables=None,
-        ad=None,
+        self, kind, p, x, cache, pos, kv_len, valid, router_bias,
+        block_tables=None, ad=None,
     ):
         """(B, C)-chunk counterpart of ``_block_decode``: all C tokens of the
         chunk are computed in parallel against the cache. pos: (B,) per-slot
-        position of the chunk's FIRST token; valid: (B, C) real-token mask —
-        rows must be contiguous prefixes (serving chunks are left-packed).
+        position of the chunk's FIRST token; kv_len: (B,) keys visible to
+        the slot's valid queries (0 for none); valid: (B, C) real-token
+        mask — rows must be contiguous prefixes (serving chunks are
+        left-packed).
         Slots with an all-False row (mid-decode, not being prefilled) keep
         their KV rows and recurrent state exactly untouched."""
         c = self.cfg
@@ -809,7 +815,7 @@ class TransformerLM:
                     cc, new, pos, block_tables, valid
                 )
                 view = lambda cc: attn_lib.gather_pages(cc, block_tables)
-            attend = self._make_attend(pos, block_tables)
+            attend = self._make_attend(pos, block_tables, kv_len)
             return self._attn_block(
                 kind, p, x, cache, pos, router_bias, valid, write, view,
                 attend, ad,
@@ -871,6 +877,8 @@ class TransformerLM:
         x = self._constrain(self._embed(params, batch))
         b, cl = x.shape[:2]
         pos = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (b,))
+        n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)  # (B,)
+        kv_len = jnp.where(n_valid > 0, pos + n_valid, 0)
         stage_ad = task_ad = None
         if adapters is not None:
             stage_ad, task_ad = self._gather_adapters(
@@ -880,14 +888,13 @@ class TransformerLM:
         x, new_caches = self._run_cached_stages(
             params, x, caches,
             lambda kind, p, h, cache, ad: self._block_prefill(
-                kind, p, h, cache, pos, valid, rb, block_tables, ad
+                kind, p, h, cache, pos, kv_len, valid, rb, block_tables, ad
             ),
             stage_ad,
         )
         # lm head over ONE hidden state per slot (its last valid token) —
         # the (B, C, V) logits slab would be C x the largest matmul in the
         # model for rows that are immediately discarded
-        n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)  # (B,)
         # max(n_valid - 1, 0) is in [0, C-1]: n_valid <= C by construction
         idx = jnp.maximum(n_valid - 1, 0)
         x_last = jnp.take_along_axis(

@@ -55,7 +55,13 @@ positions the masked attention reads. See ``docs/serving.md``.
 ``decode_dispatches`` / ``prefill_dispatches`` / ``mixed_dispatches`` /
 ``ticks`` count real jitted calls so tests and
 ``benchmarks/serve_throughput.py`` can assert the O(1)-dispatch property
-in both regimes.
+in both regimes. In paged mode on the Pallas backend,
+``attn_blocks_walked`` / ``attn_blocks_listed`` sum over dispatches the
+table blocks one layer's attention kernel walks (``cdiv(kv_len,
+block_size)`` a slot, ``kv_len`` being the keys its valid queries may see)
+and the table entries it holds (``num_slots x max_blocks``); a scan-mode
+prefill counts its C kernel calls. Their ratio is how often the kernel's
+skip of dead entries engages.
 
 Tracing: each phase of ``step()`` is a ``jax.profiler.TraceAnnotation``
 span on the profiler's clock, about a microsecond each when no profiler
@@ -87,6 +93,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.kernels.runtime import resolve_attn_backend
 from repro.models.model import TransformerLM
 from repro.serve.faults import FaultError, FaultPlan
 from repro.serve.paging import BlockAllocator, PagingSpec, RadixPrefixCache
@@ -288,6 +295,13 @@ class ContinuousBatcher:
         self.dispatch_faults = 0  # injected dispatch failures absorbed
         self.adapter_faults = 0  # injected adapter-update failures absorbed
         self.retire_faults = 0  # injected mid-retirement failures absorbed
+        # the paged kernel's walk (module docstring); counted only where
+        # the kernel runs
+        self.attn_blocks_walked = 0
+        self.attn_blocks_listed = 0
+        self._counts_walk = paging is not None and resolve_attn_backend(
+            model.cfg.attn_backend, mla=model.cfg.use_mla
+        ) == "pallas"
         self._consec_dispatch_faults = 0
         self._stalled_steps = 0  # no-progress rounds (count against run())
         self._pending_prefill: set[int] = set()  # gulp resume after a fault
@@ -549,6 +563,23 @@ class ContinuousBatcher:
         req._swap = None
         self._just_restored.add(s)
         return True
+
+    def _count_walk(self, valid: np.ndarray) -> None:
+        """Add one dispatch's kernel walk to the counters, from the host's
+        positions and the (B, C) valid mask (a contiguous prefix per row).
+        The scan prefill runs one decode kernel per chunk column i, in
+        which a slot valid there sees ``pos + i + 1`` keys."""
+        if not self._counts_walk:
+            return
+        if self.prefill_mode == "scan":
+            cols = np.arange(1, valid.shape[1] + 1)
+            kv_len = np.where(valid, self.pos[:, None] + cols, 0)
+        else:
+            n = valid.sum(axis=1)
+            kv_len = np.where(n > 0, self.pos + n, 0)
+        bs = self.paging.block_size
+        self.attn_blocks_walked += int((-(-kv_len // bs)).sum())
+        self.attn_blocks_listed += kv_len.size * self.block_tables.shape[1]
 
     def _padded_row(self, blocks: list[int]) -> np.ndarray:
         """A slot's table row at full ``max_blocks_per_slot`` width, padded
@@ -860,6 +891,7 @@ class ContinuousBatcher:
                     jnp.asarray(reset), extras, self._block_tables(),
                     self._adapter_tree(),
                 )
+                self._count_walk(valid)
             with TraceAnnotation("serve.dispatch"):
                 last, self.caches, positions = self._prefill_fn(*args)
             self.prefill_dispatches += 1
@@ -960,6 +992,7 @@ class ContinuousBatcher:
                 self.caches, jnp.asarray(self.pos), jnp.asarray(live),
                 self._block_tables(), self._adapter_tree(),
             )
+            self._count_walk(live[:, None])
         with TraceAnnotation("serve.dispatch"):
             next_tok, step_logits, self.caches = self._tick_fn(*args)
         self.ticks += 1
@@ -1044,6 +1077,7 @@ class ContinuousBatcher:
                 jnp.asarray(reset), extras, self._block_tables(),
                 self._adapter_tree(),
             )
+            self._count_walk(valid)
         with TraceAnnotation("serve.dispatch"):
             last, self.caches, positions = self._prefill_fn(*args)
         self.ticks += 1

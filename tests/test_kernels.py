@@ -429,6 +429,56 @@ def test_paged_prefill_attention_null_blocks_unreachable():
     )
 
 
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+@pytest.mark.parametrize("block", [8, 16])
+@pytest.mark.parametrize("window", [None, 6])
+def test_attention_walk_stops_at_kv_len(layout, block, window):
+    """The walk bounded by ``kv_len`` (here 0, a part block, a whole block,
+    the full table) leaves every valid row bitwise as the same kernel's
+    walk over every entry gives it, and equal to the oracle; a slot with no
+    valid query walks nothing and writes 0."""
+    rng = np.random.default_rng(block + (window or 0))
+    kvh, g, cq, hd, mb = 2, 2, 4, 64, 4
+    s = mb * block
+    # (pos, valid queries): none at a stale pos, a part block, a whole
+    # block, the full table
+    slots = ((5, 0), (2, 3), (block - cq, cq), (s - cq, cq))
+    b = len(slots)
+    pos = jnp.asarray([p for p, _ in slots], jnp.int32)
+    kv_len = jnp.asarray([p + n if n else 0 for p, n in slots], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((b, kvh, cq, g, hd)), jnp.float32)
+    k_pool, v_pool = (
+        jnp.asarray(rng.standard_normal((b * mb + 1, block, kvh, hd)),
+                    jnp.float32)
+        for _ in range(2)
+    )
+    bt = jnp.asarray(rng.permutation(np.arange(1, b * mb + 1))
+                     .reshape(b, mb).astype(np.int32))
+    if layout == "paged":
+        def attend(n):
+            return paged_prefill_attention_pallas(
+                q, k_pool, v_pool, bt, pos, n, window=window, interpret=True
+            )
+    else:
+        k, v = (pool[bt].reshape(b, s, kvh, hd) for pool in (k_pool, v_pool))
+
+        def attend(n):
+            return prefill_attention_pallas(
+                q, k, v, pos, n, block_s=block, window=window,
+                interpret=True,
+            )
+    got = np.asarray(attend(kv_len))
+    every = np.asarray(attend(jnp.full((b,), s, jnp.int32)))
+    want = np.asarray(paged_prefill_attention_reference(
+        q, k_pool, v_pool, bt, pos, window=window
+    ))
+    vb, vi = np.nonzero(np.arange(cq)[None, :] < np.asarray(slots)[:, 1:])
+    np.testing.assert_array_equal(got[vb, :, vi], every[vb, :, vi])
+    np.testing.assert_allclose(got[vb, :, vi], want[vb, :, vi], atol=3e-5)
+    assert np.isfinite(got[0]).all()
+    np.testing.assert_array_equal(got[0], 0.0)
+
+
 # ---------------------------------------------- trace-count (recompilation)
 def test_attention_ops_trace_once_across_pos_flavors():
     """Per-tick retrace regression: the public ops normalize ``pos`` (and

@@ -1,8 +1,9 @@
 """The serving step's own spans, stamps and counters, at a tiny OLMo shape:
 every phase of ``step()`` is a ``serve.*`` span in the profiler's trace,
 nested as the executor runs them; the spans change no served token; each
-request carries its admission and first-token times; and the Pallas
-kernels carry fixed names into the device trace."""
+request carries its admission and first-token times; the attention
+kernel's walk is counted as the kernel bounds it; and the Pallas kernels
+carry fixed names into the device trace."""
 import glob
 import itertools
 import os
@@ -46,13 +47,13 @@ EXPECTED = [[151, 147, 190, 229, 121], [98, 50, 218], [132, 132, 132, 169],
             [182, 0, 0, 0, 0, 0], [48, 39], [66, 66, 245, 136]]
 
 
-def _model():
+def _model(attn_backend="jnp"):
     cfg = ArchConfig(
         name="olmo", family="dense", num_layers=2, d_model=64, num_heads=4,
         num_kv_heads=4, head_dim=16, d_ff=128, vocab_size=256,
         pattern=("attn",), mlp_kind="swiglu", norm_kind="nonparam_ln",
         rope_theta=10000.0, tie_embeddings=True, num_tasks=NUM_TASKS,
-        adapter_rank=2,
+        adapter_rank=2, attn_backend=attn_backend,
     )
     model = TransformerLM(cfg, dtype=jnp.float32)
     return model, model.init(jax.random.PRNGKey(0))
@@ -171,6 +172,53 @@ def test_readmitted_request_keeps_its_first_admit_time():
     assert hog.admit_time == first_admit
     assert short.admit_time > first_admit
     assert hog.first_token_time < short.first_token_time
+
+
+# (walked, listed) after each step of two requests, prompts 12 and 3,
+# max_new 2 and 3, on 2 slots of 4 blocks of 8 (8 entries a dispatch),
+# kernel walk cdiv(kv_len, 8) a slot:
+WALKS = {
+    # fused ticks, budget 8: kv_len (8, 0); (12, 3); decode (13, 4); then
+    # slot 0 released at its stale pos 13 walks 0 beside (5,)
+    "fused": [(1, 8), (4, 16), (7, 24), (8, 32)],
+    # admission gulp (8, 3), (12, 0), decode tick (13, 4); then (0, 5)
+    "gulp": [(7, 24), (8, 32)],
+    # the gulp's 8 columns as decode kernels, kv_len pos + i + 1 where
+    # valid: 8 + 3 blocks, then 4 x 2 blocks, 64 entries a dispatch each;
+    # then the two decode ticks
+    "scan": [(22, 136), (23, 144)],
+}
+
+
+@pytest.mark.parametrize("regime", list(WALKS))
+def test_attention_walk_counters(regime):
+    """``attn_blocks_walked`` / ``attn_blocks_listed`` equal a count by
+    hand, step by step; the jnp backend walks no table and counts nothing,
+    and serves the same tokens."""
+    kw = {"fused": {"chunk_budget": 8}, "gulp": {},
+          "scan": {"prefill_mode": "scan"}}[regime]
+    served = {}
+    for backend in ("pallas", "jnp"):
+        model, params = _model(backend)
+        b = ContinuousBatcher(
+            model, params, num_slots=2, max_seq=32, prefill_chunk=8,
+            paging=PagingSpec.sized(8, 32, 64), **kw,
+        )
+        rng = np.random.default_rng(1)
+        for uid, (n, m) in enumerate(((12, 2), (3, 3))):
+            b.submit(Request(uid=uid, max_new=m,
+                             tokens=rng.integers(1, 256, n).astype(np.int32)))
+        walks = []
+        while b.queue or b.slots.any_live():
+            b.step()
+            walks.append((b.attn_blocks_walked, b.attn_blocks_listed))
+        served[backend] = {r.uid: r.out for r in b.finished}
+        if backend == "pallas":
+            assert walks == WALKS[regime]
+            assert b.slots.reqs[0] is None and b.pos[0] == 13
+        else:
+            assert walks == [(0, 0)] * len(WALKS[regime])
+    assert served["pallas"] == served["jnp"]
 
 
 def _pallas_names(fn, *args):
