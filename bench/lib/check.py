@@ -107,8 +107,7 @@ def gaps(cfg: dict, seed: int, picked: list, w: dict, control: bool):
         best, got, ctrl = ref.row_stats(
             w, jnp.asarray(tokens), jnp.asarray(vidx), ad,
             jnp.asarray(targets), w["task_head_bias"][o.req.task_id],
-            heads=cfg["num_attention_heads"], theta=cfg["rope_theta"],
-            control=control,
+            cfg=cfg, control=control,
         )
         best, got, ctrl = (np.asarray(x, np.float64)[rows]
                            for x in (best, got, ctrl))

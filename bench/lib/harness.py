@@ -63,6 +63,8 @@ class Tick:
     chunks: list  # (start, n) positions computed per slot
     emitted: int  # tokens emitted
     updates: int  # store updates made during the step
+    # what each of the program's counters (``program.counters``) rose by
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 class Recorder:
@@ -85,6 +87,7 @@ class Recorder:
         b, store = sys_.batcher, sys_.store
         self.version = store.updates if store is not None else 0
         dispatches, n_finished = b.ticks, len(b.finished)
+        before = program.counters(b)
         self._emitted = []
         t0 = time.perf_counter()
         with span("bench.step"):
@@ -115,6 +118,8 @@ class Recorder:
             b.allocator.used_blocks,
             chunks, len(self._emitted),
             (store.updates - self.version) if store is not None else 0,
+            {k: v - before.get(k, 0)
+             for k, v in program.counters(b).items()},
         )
         self.ticks.append(tick)
         self.step += 1
@@ -341,8 +346,12 @@ def run(bench, cell, cfg, mix, seed, seconds, tracing, t_process, devices,
         trace=None,
     )
     if tracing:
+        t = time.perf_counter()
         ctx.trace = trace.reduce(trace.find(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        say(f"trace: {len(ctx.trace.ops)} device operations, "
+            f"{len(ctx.trace.program_spans)} program spans, reduced in "
+            f"{time.perf_counter() - t:.3f} s")
         result["device"]["busy_s"] = ctx.trace.busy_s
         result["device"]["window_s"] = ctx.trace.window_s
         for m in spec.per_layer(bench, cell["name"]):

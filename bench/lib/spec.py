@@ -1,14 +1,19 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 A cell's configuration is ``bench/configs/<config>.json``, its traffic mix
-``bench/traffic/<traffic>.json`` and each per-layer metric's reader
-``bench/metrics/<metric>.py``. A later change adds a configuration, a mix or
-a metric by adding such files and entries, never by editing these.
+``bench/traffic/<traffic>.json``, its architecture's module
+``bench/arch/<model_type>.py`` and each per-layer metric's reader
+``bench/metrics/<metric>.py``. A later change adds a configuration, an
+architecture, a mix or a metric by adding such files and entries, never by
+editing these.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
+import re
+import sys
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -67,3 +72,19 @@ def reader(name: str):
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod.read
+
+
+def architecture(model_type: str):
+    """The module ``bench/arch/<model_type>.py``: for one architecture, the
+    program's config (``arch``), the weights drawn from a key
+    (``weights``), their place in the program's tree (``params``), the
+    adapter store's raw factors and layout (``adapter_raw``,
+    ``store_raw``) and the shape the cost counts read (``shape``)."""
+    if not re.fullmatch(r"[A-Za-z0-9_]+", model_type):
+        raise ValueError(f"model_type {model_type!r} is not a module name")
+    name = f"bench.arch.{model_type}"
+    path = BENCH / "arch" / f"{model_type}.py"
+    if name not in sys.modules and not path.is_file():
+        raise FileNotFoundError(f"model_type {model_type!r} has no "
+                                f"architecture module at {path}")
+    return importlib.import_module(name)

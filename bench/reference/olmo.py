@@ -137,8 +137,7 @@ def forward(w, tokens, versions, ad, *, heads, theta, dot=dot_f32):
     return logits
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "theta", "control"))
-def row_stats(w, tokens, versions, ad, targets, base_bias, *, heads, theta,
+def row_stats(w, tokens, versions, ad, targets, base_bias, *, cfg,
               control=False):
     """Per position: the reference's best logit, the reference's logit of
     ``targets`` (the served next token), and the control's first choice.
@@ -146,7 +145,16 @@ def row_stats(w, tokens, versions, ad, targets, base_bias, *, heads, theta,
     The reference runs in float32 at ``highest``; with ``control`` the
     float8 forward also runs over the same tokens and its argmax is read
     against the reference's logits. base_bias: (V,) the task's own head bias
-    from the weights."""
+    from the weights; cfg: the configuration, which gives the heads and the
+    rotary base."""
+    return _row_stats(w, tokens, versions, ad, targets, base_bias,
+                      heads=cfg["num_attention_heads"],
+                      theta=cfg["rope_theta"], control=control)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "theta", "control"))
+def _row_stats(w, tokens, versions, ad, targets, base_bias, *, heads, theta,
+               control):
     ref = forward(w, tokens, versions, ad, heads=heads, theta=theta)
     ref = ref + base_bias.astype(jnp.float32)
     best = jnp.max(ref, axis=-1)

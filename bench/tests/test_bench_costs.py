@@ -2,16 +2,36 @@
 peaks table."""
 import pytest
 
-from bench.lib import costs
+from bench.arch import olmo
+from bench.lib import costs, spec
 from bench.lib.peaks import PEAKS, peaks
 
-S = costs.Shape(layers=2, d=8, heads=2, kv_heads=1, head_dim=4, d_ff=16,
-                vocab=10)
+# two layers of attention (2 heads, 1 KV head, width 4) and a SwiGLU MLP of
+# width 16 over d = 8: 576 matmul parameters a layer
+S = costs.Shape(attn_layers=2, heads=2, kv_heads=1, head_dim=4,
+                body_params=2 * 576, d=8, vocab=10)
+HAND = {"model_type": "olmo", "vocab_size": 10, "hidden_size": 8,
+        "intermediate_size": 16, "num_hidden_layers": 2,
+        "num_attention_heads": 2, "num_key_value_heads": 1, "head_dim": 4}
 
 
 def test_block_params_by_hand():
-    # wq 8x8, wk 8x4, wv 8x4, wo 8x8, three 8x16 MLP matrices
-    assert S.block_params == 64 + 32 + 32 + 64 + 3 * 128
+    # wq 8x8, wk 8x4, wv 8x4, wo 8x8, three 8x16 MLP matrices, per layer
+    assert costs.Shape.of(HAND) == S
+    assert S.body_params == 2 * (64 + 32 + 32 + 64 + 3 * 128)
+
+
+def test_olmo_counts_every_layer_as_attention_and_mlp():
+    """At OLMo-1B's published widths every one of the 16 layers holds
+    attention and the MLP, as the counts took it before the shape moved to
+    the architecture's module."""
+    cfg = spec.config(spec.load(), "olmo_1b")
+    s = olmo.shape(cfg)
+    block = 4 * 2048 * 2048 + 3 * 2048 * 8192
+    assert (s.attn_layers, s.heads, s.kv_heads, s.head_dim) == (16, 16, 16,
+                                                                128)
+    assert s.body_params == 16 * block and (s.d, s.vocab) == (2048, 50304)
+    assert costs.body_flops(s, 3) == 2.0 * 16 * block * 3
 
 
 def test_body_and_head_flops_by_hand():

@@ -7,17 +7,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.arch import olmo as olmo_arch
 from bench.lib import harness, program, weights
 from bench.lib.peaks import PEAKS
 from bench.reference import olmo
 from bench.tests import tiny
+from repro.models import TransformerLM
 
 
 def test_forward_matches_program_in_float32():
     cfg = tiny.config(adapters=False)
     w = weights.model_weights(cfg, 3, 16, dtype=jnp.float32)
-    model = program.TransformerLM(program.arch(cfg), dtype=jnp.float32)
-    params = program.params_from(model, w)
+    model = TransformerLM(olmo_arch.arch(cfg), dtype=jnp.float32)
+    params = program.checked(model, olmo_arch.params(w))
     tokens = np.random.default_rng(0).integers(0, cfg["vocab_size"], 40)
     want, _ = model.forward(params, {"tokens": jnp.asarray(tokens)[None]})
     got = olmo.forward(w, jnp.asarray(tokens), None, None,

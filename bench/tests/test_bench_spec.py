@@ -15,6 +15,7 @@ def test_cell_resolves_by_name(cell):
     w = spec.cell(BENCH, cell)
     cfg = spec.config(BENCH, w["config"])
     assert cfg["reference"] and cfg["deployment"]["num_slots"] > 0
+    assert spec.architecture(cfg["model_type"]).shape(cfg).attn_layers > 0
     assert spec.traffic(w["traffic"])["loop"] in ("open", "closed")
     assert check.limits(cell)["widest_logit_gap"] > 0
     for m in spec.per_layer(BENCH, cell):
