@@ -32,6 +32,23 @@ class ArchConfig:
     norm_kind: str = "rmsnorm"
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    # False: attention without positional encoding (NoPE)
+    use_rope: bool = True
+    # softmax scale of GQA attention; None: 1/sqrt(head_dim)
+    attn_scale: float | None = None
+    # norm epsilon; None: each norm kind's own default (rmsnorm 1e-6,
+    # layernorm 1e-5)
+    norm_eps: float | None = None
+    # scalar multipliers (Granite 4.0): the input embedding is scaled by
+    # embedding_multiplier, every block's branch output by
+    # residual_multiplier before its residual add, and the logits divided
+    # by logits_scaling before the per-task head bias
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # every recurrent mixer block ("mamba", "mlstm", "slstm") is followed by
+    # its own norm and MLP, as an attention block is
+    mixer_mlp: bool = False
     sliding_window: int | None = None
     # MLA (DeepSeek-V2)
     use_mla: bool = False
@@ -50,6 +67,8 @@ class ArchConfig:
     # SSM / xLSTM
     ssm_state: int = 0
     ssm_head_dim: int = 64
+    # a bias on the Mamba-2 depthwise causal conv
+    mamba_conv_bias: bool = False
     # IO
     input_mode: str = "tokens"  # tokens | vlm | audio
     num_codebooks: int = 1
@@ -141,6 +160,7 @@ _ARCHS = [
     "musicgen_large",
     "qwen2_5_14b",
     "olmo_1b",
+    "granite_4_h_micro",
     "phi4_mini_3_8b",
     "multitask_linreg",
     "multitask_lm",

@@ -34,7 +34,7 @@ from repro.kernels.prefill_attention.kernel import (
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_s", "window", "interpret")
+    jax.jit, static_argnames=("block_s", "window", "interpret", "scale")
 )
 def decode_attention_pallas(
     q: jax.Array,  # (B, KVH, G, hd)
@@ -46,16 +46,18 @@ def decode_attention_pallas(
     block_s: int = DEFAULT_BLOCK_S,
     window: int | None = None,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     k_pool, v_pool, table = dense_as_pool(k, v, block_s)
     out = flash_attention(
         q[:, :, None], k_pool, v_pool, table, pos, window=window,
         interpret=interpret, name="dense_attention_pallas", kv_len=kv_len,
+        scale=scale,
     )
     return out[:, :, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "interpret", "scale"))
 def paged_decode_attention_pallas(
     q: jax.Array,  # (B, KVH, G, hd)
     k_pool: jax.Array,  # (num_blocks, block_size, KVH, hd) shared pool
@@ -66,6 +68,7 @@ def paged_decode_attention_pallas(
     *,
     window: int | None = None,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Flash-decode over the paged KV pool: the innermost grid axis walks
     each slot's block table and the scalar-prefetched table turns logical
@@ -74,5 +77,6 @@ def paged_decode_attention_pallas(
     out = flash_attention(
         q[:, :, None], k_pool, v_pool, block_tables, pos, window=window,
         interpret=interpret, name="paged_attention_pallas", kv_len=kv_len,
+        scale=scale,
     )
     return out[:, :, 0]

@@ -29,13 +29,14 @@ def decode_attention(
     window: int | None = None,
     block_s: int = 256,
     kv_len: jax.Array | None = None,  # (B,) keys visible (0: dead slot)
+    scale: float | None = None,  # softmax scale; None: 1/sqrt(hd)
 ) -> jax.Array:
     b, one, h, hd = q.shape
     kvh = k_cache.shape[2]
     qg = q.reshape(b, kvh, h // kvh, hd)
     out = decode_attention_pallas(
         qg, k_cache, v_cache, pos_vector(pos, b), kv_len,
-        block_s=block_s, window=window,
+        block_s=block_s, window=window, scale=scale,
     )
     return out.reshape(b, 1, h, hd)
 
@@ -49,12 +50,13 @@ def paged_decode_attention(
     *,
     window: int | None = None,
     kv_len: jax.Array | None = None,  # (B,) keys visible (0: dead slot)
+    scale: float | None = None,  # softmax scale; None: 1/sqrt(hd)
 ) -> jax.Array:
     b, one, h, hd = q.shape
     kvh = k_pool.shape[2]
     qg = q.reshape(b, kvh, h // kvh, hd)
     out = paged_decode_attention_pallas(
         qg, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
-        pos_vector(pos, b), kv_len, window=window,
+        pos_vector(pos, b), kv_len, window=window, scale=scale,
     )
     return out.reshape(b, 1, h, hd)

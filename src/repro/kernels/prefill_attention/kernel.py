@@ -138,7 +138,7 @@ def _flash_kernel(bt_ref, pos_ref, kv_len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
-                    interpret, name, kv_len=None):
+                    interpret, name, kv_len=None, scale=None):
     """Shared body of every attention kernel entry point (not jitted: the
     jitted wrappers each keep their own trace cache). ``name`` names the
     device operation in a profile.
@@ -147,7 +147,8 @@ def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
     (num_blocks, block, KVH, hd); block_tables: (B, max_blocks); pos: () or
     (B,) position of each slot's FIRST chunk token; kv_len: (B,) key
     positions each slot's valid queries may see (0: no valid query), which
-    bounds the slot's walk; None means ``pos + C`` for every slot."""
+    bounds the slot's walk; None means ``pos + C`` for every slot. scale:
+    the softmax scale, a trace-time constant (None: 1/sqrt(hd))."""
     interpret = resolve_interpret(interpret)
     b, kvh, cq, g, hd = q.shape
     block_tables = jnp.asarray(block_tables, jnp.int32)
@@ -166,7 +167,7 @@ def flash_attention(q, k_pool, v_pool, block_tables, pos, *, window,
     qf = q.reshape(b, kvh, rows, hd)
     if rp != rows:
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
-    scale = float(1.0 / (hd ** 0.5))
+    scale = float(1.0 / (hd ** 0.5)) if scale is None else float(scale)
 
     kernel = functools.partial(
         _flash_kernel, block=block, group=g, scale=scale, window=window
@@ -225,7 +226,7 @@ def dense_as_pool(k, v, block_s):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_s", "window", "interpret")
+    jax.jit, static_argnames=("block_s", "window", "interpret", "scale")
 )
 def prefill_attention_pallas(
     q: jax.Array,  # (B, KVH, C, G, hd) query slab, grouped per KV head
@@ -237,15 +238,16 @@ def prefill_attention_pallas(
     block_s: int = DEFAULT_BLOCK_S,
     window: int | None = None,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     k_pool, v_pool, table = dense_as_pool(k, v, block_s)
     return flash_attention(
         q, k_pool, v_pool, table, pos, window=window, interpret=interpret,
-        name="dense_attention_pallas", kv_len=kv_len,
+        name="dense_attention_pallas", kv_len=kv_len, scale=scale,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "interpret", "scale"))
 def paged_prefill_attention_pallas(
     q: jax.Array,  # (B, KVH, C, G, hd) query slab, grouped per KV head
     k_pool: jax.Array,  # (num_blocks, block_size, KVH, hd) shared pool
@@ -256,8 +258,10 @@ def paged_prefill_attention_pallas(
     *,
     window: int | None = None,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     return flash_attention(
         q, k_pool, v_pool, block_tables, pos, window=window,
         interpret=interpret, name="paged_attention_pallas", kv_len=kv_len,
+        scale=scale,
     )

@@ -25,6 +25,7 @@ def prefill_attention(
     window: int | None = None,
     block_s: int = 256,
     kv_len: jax.Array | None = None,  # (B,) keys visible to valid queries
+    scale: float | None = None,  # softmax scale; None: 1/sqrt(hd)
 ) -> jax.Array:
     from repro.kernels.prefill_attention.kernel import prefill_attention_pallas
 
@@ -35,7 +36,7 @@ def prefill_attention(
     qg = q.reshape(b, cq, kvh, h // kvh, hd).transpose(0, 2, 1, 3, 4)
     out = prefill_attention_pallas(
         qg, k_cache, v_cache, pos_vector(pos, b), kv_len,
-        block_s=block_s, window=window,
+        block_s=block_s, window=window, scale=scale,
     )
     return out.transpose(0, 2, 1, 3, 4).reshape(b, cq, h, hd)
 
@@ -49,6 +50,7 @@ def paged_prefill_attention(
     *,
     window: int | None = None,
     kv_len: jax.Array | None = None,  # (B,) keys visible to valid queries
+    scale: float | None = None,  # softmax scale; None: 1/sqrt(hd)
 ) -> jax.Array:
     from repro.kernels.prefill_attention.kernel import (
         paged_prefill_attention_pallas,
@@ -59,6 +61,6 @@ def paged_prefill_attention(
     qg = q.reshape(b, cq, kvh, h // kvh, hd).transpose(0, 2, 1, 3, 4)
     out = paged_prefill_attention_pallas(
         qg, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
-        pos_vector(pos, b), kv_len, window=window,
+        pos_vector(pos, b), kv_len, window=window, scale=scale,
     )
     return out.transpose(0, 2, 1, 3, 4).reshape(b, cq, h, hd)

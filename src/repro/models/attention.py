@@ -82,17 +82,20 @@ def causal_attend(
     *,
     sliding_window: int | None = None,
     q_chunk: int = 1024,
+    scale: float | None = None,
 ) -> Array:
     """Query-chunked causal (optionally windowed) attention.
 
-    q: (B, S, H, hd); k, v: (B, S, KVH, hd). Returns (B, S, H, hd).
+    q: (B, S, H, hd); k, v: (B, S, KVH, hd); scale: the softmax scale
+    (None: 1/sqrt(hd)). Returns (B, S, H, hd).
     """
     b, s, h, hd = q.shape
     hd_v = v.shape[-1]  # may differ from hd (MLA: qk dims != v dims)
     kvh = k.shape[2]
     g = h // kvh  # GQA group size — kept as an explicit einsum dim so the
     # partitioner never reshards the KV tensor to expanded heads
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
 
     q_chunk = min(q_chunk, s)
     if s % q_chunk != 0:  # fall back to one chunk when not divisible
@@ -132,6 +135,7 @@ def decode_attend(
     pos: Array,
     *,
     sliding_window: int | None = None,
+    scale: float | None = None,
 ) -> Array:
     """Chunk-of-queries attention against a cache.
 
@@ -141,12 +145,13 @@ def decode_attend(
     the cache already contains the whole chunk, written before this call).
     Causality within the chunk falls out of the same kv-position mask that
     hides unwritten cache rows: query i reads ``kv_idx <= pos + i`` only.
-    Returns (B, C, H, hd_v).
+    scale: the softmax scale (None: 1/sqrt(hd)). Returns (B, C, H, hd_v).
     """
     b, c, h, hd = q.shape
     kvh = k_cache.shape[2]
     g = h // kvh
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     qg = q.reshape(b, c, kvh, g, hd)
     # NOTE: operand-dtype dots on purpose — requesting an f32 dot against the
     # bf16 cache makes XLA-CPU hoist a full f32 convert of the scanned cache
@@ -177,6 +182,7 @@ def cached_attend(
     backend: str = "jnp",
     block_tables: Array | None = None,
     kv_len: Array | None = None,
+    scale: float | None = None,
 ) -> Array:
     """GQA chunk-of-queries attention against the cache, dispatching on the
     serving attention backend (``ArchConfig.attn_backend``, already resolved
@@ -199,6 +205,8 @@ def cached_attend(
         ``kv_len`` (B,), the keys each slot's valid queries may see (0 for
         a slot with none), bounds each slot's walk over its blocks; the
         jnp path ignores it.
+
+    ``scale`` is the softmax scale on both backends (None: 1/sqrt(hd)).
     """
     if backend == "pallas":
         from repro.kernels.decode_attention.ops import (
@@ -214,15 +222,15 @@ def cached_attend(
         if block_tables is None:
             op = decode_attention if decode else prefill_attention
             return op(q, k_cache, v_cache, pos, window=sliding_window,
-                      kv_len=kv_len)
+                      kv_len=kv_len, scale=scale)
         op = paged_decode_attention if decode else paged_prefill_attention
         return op(q, k_cache, v_cache, block_tables, pos,
-                  window=sliding_window, kv_len=kv_len)
+                  window=sliding_window, kv_len=kv_len, scale=scale)
     if block_tables is not None:
         k_cache = gather_pages(k_cache, block_tables)
         v_cache = gather_pages(v_cache, block_tables)
     return decode_attend(
-        q, k_cache, v_cache, pos, sliding_window=sliding_window
+        q, k_cache, v_cache, pos, sliding_window=sliding_window, scale=scale
     )
 
 

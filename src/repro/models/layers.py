@@ -62,7 +62,8 @@ def apply_task_lora(x: Array, ad: dict) -> Array:
 
 
 # -------------------------------------------------------------------- norms
-def rms_norm(x: Array, gain: Array | None, eps: float = 1e-6) -> Array:
+def rms_norm(x: Array, gain: Array | None, eps: float | None = None) -> Array:
+    eps = 1e-6 if eps is None else eps
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     out = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
     if gain is not None:
@@ -70,7 +71,9 @@ def rms_norm(x: Array, gain: Array | None, eps: float = 1e-6) -> Array:
     return out.astype(x.dtype)
 
 
-def layer_norm(x: Array, gain: Array | None, bias: Array | None, eps: float = 1e-5) -> Array:
+def layer_norm(x: Array, gain: Array | None, bias: Array | None,
+               eps: float | None = None) -> Array:
+    eps = 1e-5 if eps is None else eps
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
@@ -82,22 +85,25 @@ def layer_norm(x: Array, gain: Array | None, bias: Array | None, eps: float = 1e
     return out.astype(x.dtype)
 
 
-def nonparam_layer_norm(x: Array, eps: float = 1e-5) -> Array:
+def nonparam_layer_norm(x: Array, eps: float | None = None) -> Array:
     """OLMo's non-parametric LayerNorm: no gain, no bias [arXiv:2402.00838]."""
     return layer_norm(x, None, None, eps)
 
 
-def apply_norm(kind: str, x: Array, params: dict | None) -> Array:
+def apply_norm(kind: str, x: Array, params: dict | None,
+               eps: float | None = None) -> Array:
+    """eps: None gives each kind its own default."""
     if kind == "rmsnorm":
-        return rms_norm(x, params["gain"] if params else None)
+        return rms_norm(x, params["gain"] if params else None, eps)
     if kind == "layernorm":
         return layer_norm(
             x,
             params.get("gain") if params else None,
             params.get("bias") if params else None,
+            eps,
         )
     if kind == "nonparam_ln":
-        return nonparam_layer_norm(x)
+        return nonparam_layer_norm(x, eps)
     raise ValueError(kind)
 
 

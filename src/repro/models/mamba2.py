@@ -8,7 +8,11 @@ Per-layer state: conv buffer (B, kernel-1, conv_dim) and SSM state
 (B, n_heads, head_dim, d_state).
 
 Simplifications vs the reference CUDA implementation (documented in
-DESIGN.md): single B/C group (n_groups=1), no bias terms, norm-before-gate.
+DESIGN.md): single B/C group (n_groups=1), no projection biases. The
+depthwise conv takes an optional bias (``conv_bias``, as Granite 4.0-H has),
+and the output is gated before its RMSNorm (``norm(y * silu(z))``, the
+reference's gated RMSNorm with ``norm_before_gate=False``), whose epsilon
+the caller passes (``eps``; None: ``rms_norm``'s default).
 """
 from __future__ import annotations
 
@@ -29,10 +33,11 @@ def dims(d_model: int, d_state: int, head_dim: int = 64, expand: int = 2):
     return d_inner, n_heads, conv_dim
 
 
-def init_mamba2(key, d_model: int, d_state: int, head_dim: int, dtype):
+def init_mamba2(key, d_model: int, d_state: int, head_dim: int, dtype,
+                conv_bias: bool = False):
     d_inner, n_heads, conv_dim = dims(d_model, d_state, head_dim)
     ks = jax.random.split(key, 6)
-    return {
+    p = {
         # in_proj -> [z (d_inner), x (d_inner), B (ds), C (ds), dt (nh)]
         "w_in": dense_init(ks[0], (d_model, 2 * d_inner + 2 * d_state + n_heads), dtype=dtype),
         "conv_w": dense_init(ks[1], (CONV_K, conv_dim), dtype=dtype),
@@ -42,6 +47,13 @@ def init_mamba2(key, d_model: int, d_state: int, head_dim: int, dtype):
         "norm_gain": jnp.zeros((d_inner,), dtype),
         "w_out": dense_init(ks[2], (d_inner, d_model), dtype=dtype),
     }
+    if conv_bias:
+        # torch's Conv1d default: uniform in +-1/sqrt(fan_in), fan_in = K
+        bound = CONV_K ** -0.5
+        p["conv_b"] = jax.random.uniform(
+            ks[3], (conv_dim,), jnp.float32, -bound, bound
+        ).astype(dtype)
+    return p
 
 
 def _split_proj(params, x, d_model, d_state, head_dim):
@@ -53,10 +65,11 @@ def _split_proj(params, x, d_model, d_state, head_dim):
     return z, xbc, dt, d_inner, n_heads
 
 
-def _causal_conv(xbc: Array, conv_w: Array, prefix: Array | None = None) -> Array:
+def _causal_conv(xbc: Array, conv_w: Array, prefix: Array | None = None,
+                 bias: Array | None = None) -> Array:
     """Depthwise causal conv over time. xbc: (B, S, C); prefix: optional
     (B, K-1, C) window carried in from earlier tokens (zeros when absent —
-    the sequence starts here)."""
+    the sequence starts here); bias: optional (C,)."""
     if prefix is None:
         pad = jnp.pad(xbc, ((0, 0), (CONV_K - 1, 0), (0, 0)))
     else:
@@ -65,12 +78,14 @@ def _causal_conv(xbc: Array, conv_w: Array, prefix: Array | None = None) -> Arra
         pad[:, k : k + xbc.shape[1], :] * conv_w[k][None, None, :]
         for k in range(CONV_K)
     )
+    if bias is not None:
+        out = out + bias
     return jax.nn.silu(out)
 
 
 def mamba2_full(
     params, x, *, d_state: int, head_dim: int, chunk: int = 256,
-    state=None, valid: Array | None = None,
+    state=None, valid: Array | None = None, eps: float | None = None,
 ):
     """Full-sequence chunked SSD. x: (B, S, d_model) -> (y, final_state).
 
@@ -105,7 +120,8 @@ def mamba2_full(
         conv_tail = jnp.take_along_axis(
             window, idx[:, :, None], axis=1, mode="promise_in_bounds"
         )
-    xbc = _causal_conv(xbc, params["conv_w"], prefix=conv_prefix)
+    xbc = _causal_conv(xbc, params["conv_w"], prefix=conv_prefix,
+                       bias=params.get("conv_b"))
     xs = xbc[..., :d_inner].reshape(bsz, s, nh, head_dim)
     b_in = xbc[..., d_inner : d_inner + d_state]  # (B, S, ds)
     c_in = xbc[..., d_inner + d_state :]  # (B, S, ds)
@@ -159,11 +175,12 @@ def mamba2_full(
     y = y_chunks.swapaxes(0, 1).reshape(bsz, s, nh, head_dim)
     y = y + params["d_skip"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(bsz, s, d_inner).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), params["norm_gain"])
+    y = rms_norm(y * jax.nn.silu(z), params["norm_gain"], eps)
     return matmul(y, params["w_out"]), (conv_tail, s_final)
 
 
-def mamba2_step(params, x, state, *, d_state: int, head_dim: int, live=None):
+def mamba2_step(params, x, state, *, d_state: int, head_dim: int, live=None,
+                eps: float | None = None):
     """Single-token decode. x: (B, 1, d_model); state = (conv_tail, ssm);
     live: optional (B,) bool — slots with live=False emit garbage output but
     keep their state untouched (continuous-batching dead slots)."""
@@ -171,9 +188,10 @@ def mamba2_step(params, x, state, *, d_state: int, head_dim: int, live=None):
     conv_tail, ssm = state  # (B, K-1, conv_dim), (B, nh, hd, ds)
     z, xbc, dt, d_inner, nh = _split_proj(params, x, d_model, d_state, head_dim)
     window = jnp.concatenate([conv_tail, xbc], axis=1)  # (B, K, conv_dim)
-    conv_out = jax.nn.silu(
-        jnp.einsum("bkc,kc->bc", window, params["conv_w"])
-    )[:, None, :]
+    conv_out = jnp.einsum("bkc,kc->bc", window, params["conv_w"])
+    if "conv_b" in params:
+        conv_out = conv_out + params["conv_b"]
+    conv_out = jax.nn.silu(conv_out)[:, None, :]
     new_tail = window[:, 1:, :]
     xs = conv_out[..., :d_inner].reshape(bsz, nh, head_dim)
     b_in = conv_out[:, 0, d_inner : d_inner + d_state]  # (B, ds)
@@ -188,6 +206,6 @@ def mamba2_step(params, x, state, *, d_state: int, head_dim: int, live=None):
     y = jnp.einsum("bn,bhdn->bhd", c_in.astype(jnp.float32), ssm_new)
     y = y + params["d_skip"][None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(bsz, 1, d_inner).astype(x.dtype)
-    y = rms_norm(y * jax.nn.silu(z), params["norm_gain"])
+    y = rms_norm(y * jax.nn.silu(z), params["norm_gain"], eps)
     new_state = freeze_dead_slots((new_tail, ssm_new), state, live)
     return matmul(y, params["w_out"]), new_state

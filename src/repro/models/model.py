@@ -12,6 +12,10 @@ Four entry points, all pure functions of (params, inputs):
   * ``prefill_step`` — a (B, C) prompt chunk against caches at per-slot
     offsets, all C tokens computed in parallel (serving prefill).
 
+Every block's mixer runs under a ``jax.named_scope`` ("attention", "mamba2",
+"mlstm", "slstm") and its MLP under "mlp" ("moe"), so that a profile of the
+compiled step can put device time on them.
+
 Multi-task personalization (the paper's technique) lives in ``params['task']``:
 per-task final-norm gain, lm-head bias and (MoE) router bias, all with a
 leading task axis that the launcher shards over the data mesh axis. The
@@ -83,23 +87,21 @@ class TransformerLM:
                 p["mlp"] = init_mlp(ks[1], c.d_model, c.d_ff, c.mlp_kind, self.dtype)
             return p
         if kind == "mamba":
-            return {
-                "norm": init_norm(c.norm_kind, c.d_model, self.dtype),
-                "mamba": mamba_lib.init_mamba2(
-                    ks[0], c.d_model, c.ssm_state, c.ssm_head_dim, self.dtype
-                ),
-            }
-        if kind == "mlstm":
-            return {
-                "norm": init_norm(c.norm_kind, c.d_model, self.dtype),
-                "mlstm": xlstm_lib.init_mlstm(ks[0], c.d_model, c.num_heads),
-            }
-        if kind == "slstm":
-            return {
-                "norm": init_norm(c.norm_kind, c.d_model, self.dtype),
-                "slstm": xlstm_lib.init_slstm(ks[0], c.d_model, c.num_heads),
-            }
-        raise ValueError(kind)
+            mixer = mamba_lib.init_mamba2(
+                ks[0], c.d_model, c.ssm_state, c.ssm_head_dim, self.dtype,
+                c.mamba_conv_bias,
+            )
+        elif kind == "mlstm":
+            mixer = xlstm_lib.init_mlstm(ks[0], c.d_model, c.num_heads)
+        elif kind == "slstm":
+            mixer = xlstm_lib.init_slstm(ks[0], c.d_model, c.num_heads)
+        else:
+            raise ValueError(kind)
+        p = {"norm": init_norm(c.norm_kind, c.d_model, self.dtype), kind: mixer}
+        if c.mixer_mlp:
+            p["norm2"] = init_norm(c.norm_kind, c.d_model, self.dtype)
+            p["mlp"] = init_mlp(ks[1], c.d_model, c.d_ff, c.mlp_kind, self.dtype)
+        return p
 
     def _stage_patterns(self) -> list[tuple[str, ...]]:
         c = self.cfg
@@ -174,6 +176,8 @@ class TransformerLM:
                     batch["vision_embeds"].astype(x.dtype),
                     x,
                 )
+        if c.embedding_multiplier != 1.0:
+            x = x * c.embedding_multiplier
         return x
 
     # Per-task param gathers must CLIP out-of-range ids, not use jnp.take's
@@ -196,7 +200,7 @@ class TransformerLM:
 
     def _logits(self, params, x, batch, task_ad=None) -> Array:
         c = self.cfg
-        x = apply_norm(c.norm_kind, x, params["final_norm"] or None)
+        x = self._norm(x, params["final_norm"])
         if "final_gain" in params["task"] and "task_ids" in batch:
             gain = jnp.take(
                 params["task"]["final_gain"], batch["task_ids"], axis=0,
@@ -213,6 +217,8 @@ class TransformerLM:
             x, head, (((x.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if c.logits_scaling != 1.0:
+            logits = logits / c.logits_scaling
         if "task_ids" in batch:
             hb = jnp.take(
                 params["task"]["head_bias"], batch["task_ids"], axis=0,
@@ -232,72 +238,123 @@ class TransformerLM:
             logits = logits.reshape(b, s, c.num_codebooks, c.vocab_size)
         return logits
 
+    # ------------------------------------------------------ block pieces
+    def _norm(self, x, p) -> Array:
+        c = self.cfg
+        return apply_norm(c.norm_kind, x, p or None, c.norm_eps)
+
+    def _residual(self, x, out) -> Array:
+        """The residual add, the branch scaled by ``residual_multiplier``."""
+        r = self.cfg.residual_multiplier
+        return x + (out if r == 1.0 else out * r)
+
+    def _rope(self, t, positions) -> Array:
+        c = self.cfg
+        return attn_lib.apply_rope(t, positions, c.rope_theta) if c.use_rope else t
+
+    def _ffn(self, kind, p, x, router_bias, live=None, ad=None):
+        """The second half of every block that has one: norm, the MLP
+        (name scope ``mlp``) or MoE (``moe``), the per-task MLP delta where
+        the adapters hold one, the residual add. live: None, (B,) or (B, C)
+        — ``apply_moe`` takes either. Returns (x, aux)."""
+        c = self.cfg
+        h = self._norm(x, p["norm2"])
+        aux = jnp.zeros((), jnp.float32)
+        if kind == "attn_moe":
+            with jax.named_scope("moe"):
+                ff, aux = apply_moe(
+                    p["moe"], h, top_k=c.top_k,
+                    capacity_factor=c.capacity_factor,
+                    router_bias=router_bias, groups=c.moe_groups,
+                    fsdp_gather=c.fsdp_gather_moe, live=live,
+                )
+        else:
+            with jax.named_scope("mlp"):
+                ff = apply_mlp(p["mlp"], h, c.mlp_kind)
+        if ad is not None and "mlp" in ad:
+            ff = ff + apply_task_lora(h, ad["mlp"])
+        return self._residual(x, ff), aux
+
+    # name scope each recurrent mixer runs under (the benchmark's trace
+    # reduction puts device time on a mixer by it)
+    _MIXER_SCOPES = {"mamba": "mamba2", "mlstm": "mlstm", "slstm": "slstm"}
+
+    def _recurrent_block(self, kind, p, x, mix, ad=None):
+        """A recurrent block: norm, ``mix(h) -> (out, state)`` under the
+        mixer's name scope, the per-task delta off the normed input, the
+        residual add, then (``cfg.mixer_mlp``) the block's own MLP.
+        Returns (x, state)."""
+        h = self._norm(x, p["norm"])
+        with jax.named_scope(self._MIXER_SCOPES[kind]):
+            out, state = mix(h)
+        if ad is not None:
+            out = out + apply_task_lora(h, ad["out"])
+        x = self._residual(x, out)
+        if self.cfg.mixer_mlp:
+            x, _ = self._ffn(kind, p, x, None, ad=ad)
+        return x, state
+
     # ----------------------------------------------------- full-seq blocks
     def _block_full(self, kind, p, x, positions, router_bias, want_cache):
         """Returns (x, cache_entry, aux). cache entry is the FULL-SEQ state
         (attn: (k, v) over the sequence; ssm: final state)."""
         c = self.cfg
-        aux = jnp.zeros((), jnp.float32)
-        cache = ()
-        if kind in ("attn", "attn_moe", "shared_attn"):
-            h = apply_norm(c.norm_kind, x, p["norm1"] or None)
-            if c.use_mla:
-                out, (c_kv, k_rope) = attn_lib.mla_full(
-                    p["attn"], h, self._mla_dims(), positions, c.rope_theta,
-                    q_chunk=c.q_chunk,
-                )
-                if want_cache:
-                    cache = (c_kv, k_rope)
-            else:
-                q, k, v = attn_lib.gqa_project(
-                    p["attn"], h, c.num_heads, c.num_kv_heads, c.head_dim
-                )
-                q = attn_lib.apply_rope(q, positions, c.rope_theta)
-                k = attn_lib.apply_rope(k, positions, c.rope_theta)
-                o = attn_lib.causal_attend(
-                    q, k, v, sliding_window=c.sliding_window, q_chunk=c.q_chunk
-                )
-                b, s, _, _ = o.shape
-                out = matmul(o.reshape(b, s, c.num_heads * c.head_dim), p["attn"]["wo"])
-                if want_cache:
-                    cache = (k, v)
-            x = x + out
-            h = apply_norm(c.norm_kind, x, p["norm2"] or None)
-            if kind == "attn_moe":
-                ff, aux = apply_moe(
-                    p["moe"], h, top_k=c.top_k, capacity_factor=c.capacity_factor,
-                    router_bias=router_bias, groups=c.moe_groups,
-                    fsdp_gather=c.fsdp_gather_moe,
-                )
-            else:
-                ff = apply_mlp(p["mlp"], h, c.mlp_kind)
-            return x + ff, cache, aux
+        if kind in self._ATTN_KINDS:
+            cache = ()
+            h = self._norm(x, p["norm1"])
+            with jax.named_scope("attention"):
+                if c.use_mla:
+                    out, (c_kv, k_rope) = attn_lib.mla_full(
+                        p["attn"], h, self._mla_dims(), positions,
+                        c.rope_theta, q_chunk=c.q_chunk,
+                    )
+                    if want_cache:
+                        cache = (c_kv, k_rope)
+                else:
+                    q, k, v = attn_lib.gqa_project(
+                        p["attn"], h, c.num_heads, c.num_kv_heads, c.head_dim
+                    )
+                    q, k = self._rope(q, positions), self._rope(k, positions)
+                    o = attn_lib.causal_attend(
+                        q, k, v, sliding_window=c.sliding_window,
+                        q_chunk=c.q_chunk, scale=c.attn_scale,
+                    )
+                    b, s, _, _ = o.shape
+                    out = matmul(
+                        o.reshape(b, s, c.num_heads * c.head_dim),
+                        p["attn"]["wo"],
+                    )
+                    if want_cache:
+                        cache = (k, v)
+            x = self._residual(x, out)
+            x, aux = self._ffn(kind, p, x, router_bias)
+            return x, cache, aux
         if kind == "mamba":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
-            out, state = mamba_lib.mamba2_full(
-                p["mamba"], h, d_state=c.ssm_state, head_dim=c.ssm_head_dim,
-                chunk=c.mamba_chunk,
-            )
-            return x + out, (state if want_cache else ()), aux
-        if kind == "mlstm":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
-            if c.xlstm_parallel:
-                out, state = xlstm_lib.mlstm_chunkwise(
-                    p["mlstm"], h, n_heads=c.num_heads,
-                    chunk=c.xlstm_chunk or 64,
+            def mix(h):
+                return mamba_lib.mamba2_full(
+                    p["mamba"], h, d_state=c.ssm_state,
+                    head_dim=c.ssm_head_dim, chunk=c.mamba_chunk,
+                    eps=c.norm_eps,
                 )
-            else:
-                out, state = xlstm_lib.mlstm_full(
+        elif kind == "mlstm":
+            def mix(h):
+                if c.xlstm_parallel:
+                    return xlstm_lib.mlstm_chunkwise(
+                        p["mlstm"], h, n_heads=c.num_heads,
+                        chunk=c.xlstm_chunk or 64,
+                    )
+                return xlstm_lib.mlstm_full(
                     p["mlstm"], h, n_heads=c.num_heads, chunk=c.xlstm_chunk
                 )
-            return x + out, (state if want_cache else ()), aux
-        if kind == "slstm":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
-            out, state = xlstm_lib.slstm_full(
-                p["slstm"], h, n_heads=c.num_heads, chunk=c.xlstm_chunk
-            )
-            return x + out, (state if want_cache else ()), aux
-        raise ValueError(kind)
+        elif kind == "slstm":
+            def mix(h):
+                return xlstm_lib.slstm_full(
+                    p["slstm"], h, n_heads=c.num_heads, chunk=c.xlstm_chunk
+                )
+        else:
+            raise ValueError(kind)
+        x, state = self._recurrent_block(kind, p, x, mix)
+        return x, (state if want_cache else ()), jnp.zeros((), jnp.float32)
 
     def _constrain(self, x):
         spec = self.cfg.activation_sharding
@@ -555,6 +612,7 @@ class TransformerLM:
         return lambda q, kc, vc: attn_lib.cached_attend(
             q, kc, vc, pos, sliding_window=c.sliding_window,
             backend=backend, block_tables=block_tables, kv_len=kv_len,
+            scale=c.attn_scale,
         )
 
     def _gather_adapters(self, adapters, task_ids):
@@ -602,53 +660,43 @@ class TransformerLM:
         c = self.cfg
         b, cl = x.shape[:2]
         q_pos = pos[:, None] + jnp.arange(cl)[None, :]  # (B, C)
-        h = apply_norm(c.norm_kind, x, p["norm1"] or None)
-        if c.use_mla:
-            c_cache, r_cache = cache
-            c_kv = matmul(h, p["attn"]["w_dkv"])  # (B, C, r)
-            k_rope = attn_lib.apply_rope(
-                matmul(h, p["attn"]["w_krope"])[:, :, None, :],
-                q_pos,
-                c.rope_theta,
-            )[:, :, 0, :]
-            c_cache = write(c_cache, c_kv)
-            r_cache = write(r_cache, k_rope)
-            out = attn_lib.mla_decode(
-                p["attn"], h, self._mla_dims(), view(c_cache),
-                view(r_cache), pos, c.rope_theta,
-            )
-            new_cache = (c_cache, r_cache)
-        else:
-            k_cache, v_cache = cache
-            q, k, v = attn_lib.gqa_project(
-                p["attn"], h, c.num_heads, c.num_kv_heads, c.head_dim
-            )
-            q = attn_lib.apply_rope(q, q_pos, c.rope_theta)
-            k = attn_lib.apply_rope(k, q_pos, c.rope_theta)
-            k_cache = write(k_cache, k)
-            v_cache = write(v_cache, v)
-            o = attend(q, k_cache, v_cache)
-            out = matmul(
-                o.reshape(b, cl, c.num_heads * c.head_dim), p["attn"]["wo"]
-            )
-            new_cache = (k_cache, v_cache)
+        h = self._norm(x, p["norm1"])
+        with jax.named_scope("attention"):
+            if c.use_mla:
+                c_cache, r_cache = cache
+                c_kv = matmul(h, p["attn"]["w_dkv"])  # (B, C, r)
+                k_rope = attn_lib.apply_rope(
+                    matmul(h, p["attn"]["w_krope"])[:, :, None, :],
+                    q_pos,
+                    c.rope_theta,
+                )[:, :, 0, :]
+                c_cache = write(c_cache, c_kv)
+                r_cache = write(r_cache, k_rope)
+                out = attn_lib.mla_decode(
+                    p["attn"], h, self._mla_dims(), view(c_cache),
+                    view(r_cache), pos, c.rope_theta,
+                )
+                new_cache = (c_cache, r_cache)
+            else:
+                k_cache, v_cache = cache
+                q, k, v = attn_lib.gqa_project(
+                    p["attn"], h, c.num_heads, c.num_kv_heads, c.head_dim
+                )
+                q, k = self._rope(q, q_pos), self._rope(k, q_pos)
+                k_cache = write(k_cache, k)
+                v_cache = write(v_cache, v)
+                o = attend(q, k_cache, v_cache)
+                out = matmul(
+                    o.reshape(b, cl, c.num_heads * c.head_dim), p["attn"]["wo"]
+                )
+                new_cache = (k_cache, v_cache)
         if ad is not None:
             # parallel per-task delta off the same normed input (h is still
             # the norm1 output on both the GQA and MLA paths)
             out = out + apply_task_lora(h, ad["attn"])
-        x = x + out
-        h = apply_norm(c.norm_kind, x, p["norm2"] or None)
-        if kind == "attn_moe":
-            ff, _ = apply_moe(
-                p["moe"], h, top_k=c.top_k, capacity_factor=c.capacity_factor,
-                router_bias=router_bias, groups=c.moe_groups,
-                fsdp_gather=c.fsdp_gather_moe, live=moe_live,
-            )
-        else:
-            ff = apply_mlp(p["mlp"], h, c.mlp_kind)
-        if ad is not None:
-            ff = ff + apply_task_lora(h, ad["mlp"])
-        return x + ff, new_cache
+        x = self._residual(x, out)
+        x, _ = self._ffn(kind, p, x, router_bias, moe_live, ad)
+        return x, new_cache
 
     def _block_decode(
         self, kind, p, x, cache, pos, kv_len, router_bias, live=None,
@@ -676,31 +724,24 @@ class TransformerLM:
                 attend, ad,
             )
         if kind == "mamba":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
-            out, state = mamba_lib.mamba2_step(
-                p["mamba"], h, cache, d_state=c.ssm_state,
-                head_dim=c.ssm_head_dim, live=live,
-            )
-            if ad is not None:
-                out = out + apply_task_lora(h, ad["out"])
-            return x + out, state
-        if kind == "mlstm":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
-            out, state = xlstm_lib.mlstm_step(
-                p["mlstm"], h, cache, n_heads=c.num_heads, live=live
-            )
-            if ad is not None:
-                out = out + apply_task_lora(h, ad["out"])
-            return x + out, state
-        if kind == "slstm":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
-            out, state = xlstm_lib.slstm_step(
-                p["slstm"], h, cache, n_heads=c.num_heads, live=live
-            )
-            if ad is not None:
-                out = out + apply_task_lora(h, ad["out"])
-            return x + out, state
-        raise ValueError(kind)
+            def mix(h):
+                return mamba_lib.mamba2_step(
+                    p["mamba"], h, cache, d_state=c.ssm_state,
+                    head_dim=c.ssm_head_dim, live=live, eps=c.norm_eps,
+                )
+        elif kind == "mlstm":
+            def mix(h):
+                return xlstm_lib.mlstm_step(
+                    p["mlstm"], h, cache, n_heads=c.num_heads, live=live
+                )
+        elif kind == "slstm":
+            def mix(h):
+                return xlstm_lib.slstm_step(
+                    p["slstm"], h, cache, n_heads=c.num_heads, live=live
+                )
+        else:
+            raise ValueError(kind)
+        return self._recurrent_block(kind, p, x, mix, ad)
 
     def _run_cached_stages(self, params, x, caches, block_fn,
                            stage_adapters=None):
@@ -821,39 +862,33 @@ class TransformerLM:
                 attend, ad,
             )
         if kind == "mamba":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
-            out, state = mamba_lib.mamba2_full(
-                p["mamba"], h, d_state=c.ssm_state, head_dim=c.ssm_head_dim,
-                chunk=c.mamba_chunk, state=cache, valid=valid,
-            )
-            if ad is not None:
-                out = out + apply_task_lora(h, ad["out"])
-            return x + out, state
-        if kind == "mlstm":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
+            def mix(h):
+                return mamba_lib.mamba2_full(
+                    p["mamba"], h, d_state=c.ssm_state,
+                    head_dim=c.ssm_head_dim, chunk=c.mamba_chunk, state=cache,
+                    valid=valid, eps=c.norm_eps,
+                )
+        elif kind == "mlstm":
             # always the EXACT sequential cell, never mlstm_chunkwise even
             # under cfg.xlstm_parallel: serving prefill must continue decode
             # numerics bit-for-bit (the chunkwise reformulation reassociates
             # floats ~1e-4, enough to flip near-tied greedy argmax against
             # the decode/scan path); chunkwise stays a train/full-prefill
             # lever where there is no decode stream to stay consistent with
-            out, state = xlstm_lib.mlstm_full(
-                p["mlstm"], h, n_heads=c.num_heads, chunk=c.xlstm_chunk,
-                state=cache, valid=valid,
-            )
-            if ad is not None:
-                out = out + apply_task_lora(h, ad["out"])
-            return x + out, state
-        if kind == "slstm":
-            h = apply_norm(c.norm_kind, x, p["norm"] or None)
-            out, state = xlstm_lib.slstm_full(
-                p["slstm"], h, n_heads=c.num_heads, chunk=c.xlstm_chunk,
-                state=cache, valid=valid,
-            )
-            if ad is not None:
-                out = out + apply_task_lora(h, ad["out"])
-            return x + out, state
-        raise ValueError(kind)
+            def mix(h):
+                return xlstm_lib.mlstm_full(
+                    p["mlstm"], h, n_heads=c.num_heads, chunk=c.xlstm_chunk,
+                    state=cache, valid=valid,
+                )
+        elif kind == "slstm":
+            def mix(h):
+                return xlstm_lib.slstm_full(
+                    p["slstm"], h, n_heads=c.num_heads, chunk=c.xlstm_chunk,
+                    state=cache, valid=valid,
+                )
+        else:
+            raise ValueError(kind)
+        return self._recurrent_block(kind, p, x, mix, ad)
 
     def prefill_step(self, params, batch, caches, positions, valid,
                      block_tables=None, adapters=None):

@@ -77,7 +77,11 @@ def test_decode_step(arch):
     assert bool(jnp.all(jnp.isfinite(logits)))
 
 
-@pytest.mark.parametrize("arch", ["qwen2_5_14b", "zamba2_7b", "xlstm_350m", "deepseek_v2_236b"])
+@pytest.mark.parametrize(
+    "arch",
+    ["qwen2_5_14b", "zamba2_7b", "xlstm_350m", "deepseek_v2_236b",
+     "granite_4_h_micro"],
+)
 def test_prefill_decode_consistency(arch):
     """prefill(t_0..t_{n-1}) then decode(t_n) must match the full forward."""
     import dataclasses

@@ -339,6 +339,43 @@ def test_prefill_attention_matches_model_path():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5)
 
 
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+@pytest.mark.parametrize("cq", [1, 5])
+def test_attention_kernel_takes_softmax_scale(layout, cq):
+    """At a softmax scale other than 1/sqrt(hd) (Granite's 1/64 at hd 64),
+    the Pallas kernel (interpret mode) equals the jnp path, decode (C == 1)
+    and chunk, dense and paged; and the scale is not ignored."""
+    from repro.models.attention import cached_attend
+
+    rng = np.random.default_rng(17)
+    b, kvh, g, hd, block = 2, 2, 4, 64, 16
+    max_blocks = 4
+    q = jnp.asarray(rng.standard_normal((b, cq, kvh * g, hd)), jnp.float32)
+    pos = jnp.asarray([40, 9], jnp.int32)
+    kv_len = pos + cq
+    if layout == "paged":
+        num_blocks = b * max_blocks + 1
+        shape = (num_blocks, block, kvh, hd)
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, num_blocks)).reshape(b, max_blocks)
+            .astype(np.int32)
+        )
+    else:
+        shape, tables = (b, block * max_blocks, kvh, hd), None
+    k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def attend(backend, scale):
+        return np.asarray(cached_attend(
+            q, k, v, pos, backend=backend, block_tables=tables,
+            kv_len=kv_len, scale=scale,
+        ))
+
+    got = attend("pallas", 1 / 64)
+    np.testing.assert_allclose(got, attend("jnp", 1 / 64), atol=3e-5)
+    assert np.abs(got - attend("pallas", None)).max() > 1e-2
+
+
 if HAVE_HYPOTHESIS:
 
     @settings(deadline=None, max_examples=15)

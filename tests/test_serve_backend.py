@@ -57,10 +57,13 @@ def _smoke(arch):
 
 
 # ----------------------------------------------------- GQA: kernels active
-@pytest.mark.parametrize("arch", ["qwen2_5_14b", "mixtral_8x22b"])
+@pytest.mark.parametrize(
+    "arch", ["qwen2_5_14b", "mixtral_8x22b", "granite_4_h_micro"]
+)
 def test_pallas_backend_dense_parity(arch):
     """Flash decode + flash prefill == jnp masked einsum, token-for-token
-    (qwen: GQA with QKV bias; mixtral: sliding-window GQA + MoE)."""
+    (qwen: GQA with QKV bias; mixtral: sliding-window GQA + MoE; granite:
+    NoPE GQA at softmax scale 1/64 beside mamba2 mixers)."""
     cfg, params = _smoke(arch)
     assert _greedy_outputs(cfg, params, "pallas") == _greedy_outputs(
         cfg, params, "jnp"
@@ -77,6 +80,17 @@ def test_pallas_backend_paged_parity(block_size):
     paged_jnp = _greedy_outputs(cfg, params, "jnp", paging=spec)
     dense_jnp = _greedy_outputs(cfg, params, "jnp")
     assert paged_pallas == paged_jnp == dense_jnp
+
+
+def test_pallas_backend_paged_parity_hybrid():
+    """The hybrid on the paged kernels: granite's NoPE GQA at scale 1/64
+    walks the block table beside dense mamba2 states, token-for-token with
+    the jnp gather path and the dense jnp run."""
+    cfg, params = _smoke("granite_4_h_micro")
+    spec = PagingSpec.sized(8, 24, pool_tokens=2 * 24)
+    paged_pallas = _greedy_outputs(cfg, params, "pallas", paging=spec)
+    assert paged_pallas == _greedy_outputs(cfg, params, "jnp", paging=spec)
+    assert paged_pallas == _greedy_outputs(cfg, params, "jnp")
 
 
 def test_pallas_backend_paged_parity_sliding_window():

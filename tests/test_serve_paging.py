@@ -66,7 +66,8 @@ def _dense_outputs(arch):
 @pytest.mark.parametrize("block_size", [8, 16])
 @pytest.mark.parametrize(
     "arch",
-    ["qwen2_5_14b", "deepseek_v2_236b", "mixtral_8x22b", "zamba2_7b"],
+    ["qwen2_5_14b", "deepseek_v2_236b", "mixtral_8x22b", "zamba2_7b",
+     "granite_4_h_micro"],
 )
 def test_paged_matches_dense_token_for_token(arch, block_size):
     """Same model/requests/slots, only the cache layout differs: the paged

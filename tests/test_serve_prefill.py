@@ -34,6 +34,7 @@ ARCHS = [
     "mixtral_8x22b",    # sliding window + MoE
     "zamba2_7b",        # mamba2 SSD + shared_attn hybrid
     "xlstm_350m",       # mLSTM + sLSTM recurrences
+    "granite_4_h_micro",  # mamba2 + NoPE GQA, each mixer with its own MLP
 ]
 
 

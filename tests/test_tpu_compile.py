@@ -141,3 +141,34 @@ def test_olmo_serving_step_fits_one_chip(one_chip, step, monkeypatch):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert peak < HBM_BYTES, f"{peak} bytes do not fit one chip"
     assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
+
+
+def test_granite_serving_step_fits_one_chip(one_chip, monkeypatch):
+    """The full-width granite-4.0-h-micro Pallas paged step, bf16, as the
+    benchmark deploys it (16 slots of 8192 positions, a pool of 131072
+    tokens in 16-token blocks, 64-token chunks), compiles for one chip and
+    fits its HBM: weights, 36 mamba2 states and 4 layers' KV pools."""
+    slots, max_seq, pool = 16, 8192, 131072
+    cfg = dataclasses.replace(get("granite_4_h_micro"), attn_backend="pallas")
+    model = TransformerLM(cfg, dtype=jnp.bfloat16)
+    paging = PagingSpec.sized(BLOCK, max_seq, pool)
+    params = _shapes(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    caches = _shapes(
+        one_chip, jax.eval_shape(lambda: model.init_cache(slots, max_seq, paging))
+    )
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    monkeypatch.setattr(flash_kernel, "resolve_interpret", lambda _: False)
+    _, prefill_chunk = make_serve_step(model, max_seq, paging)
+    args = (params, sds((slots, CHUNK), jnp.int32), sds((slots,), jnp.int32),
+            caches, sds((slots,), jnp.int32), sds((slots, CHUNK), bool),
+            sds((slots,), bool), {},
+            sds((slots, paging.max_blocks_per_slot), jnp.int32))
+    compiled = prefill_chunk.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert peak < HBM_BYTES, f"{peak} bytes do not fit one chip"
+    assert "tpu_custom_call" in compiled.as_text()
